@@ -112,6 +112,8 @@ def main() -> None:
                 sys.exit(2)
             json_path = argv[i + 1]
 
+    from repro.launch.compile_cache import place_compile_cache
+    place_compile_cache()
     from benchmarks import (common, fig9_cost_ladder, table1_rfloor_matrix,
                             table2_dispatch_ab, table4_batch_sweep,
                             table6_attention_backends, table7_quant_matrix,

@@ -51,9 +51,25 @@ CHIPS: Dict[str, ChipSpec] = {
 TPU_LADDER = [TPU_V5E, TPU_V4, TPU_V6E, TPU_V5P]          # ordered by HBM bw
 GPU_LADDER = [GPU_L4, GPU_L40S, GPU_A100, GPU_H100]       # the paper's ladder
 
-# Primary roofline target (assignment-pinned).
+# Primary roofline target of the analytic tables (assignment-pinned).
 DEFAULT_CHIP = TPU_V5E
+
+# The chip JAX runs on, by ``jax.devices()[0].device_kind``.  Peaks are
+# Google Cloud's published "TPU v5e" figures.  A device missing here has
+# no peaks to measure against: looking it up is an error, never a
+# default.
+DEVICE_KINDS: Dict[str, ChipSpec] = {"TPU v5 lite": TPU_V5E}
 
 
 def get_chip(name: str) -> ChipSpec:
     return CHIPS[name]
+
+
+def chip_for_device_kind(device_kind: str) -> ChipSpec:
+    """The ChipSpec of a device JAX reports; unknown kinds raise."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no chip spec for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_KINDS)})") from None

@@ -19,14 +19,14 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            k_scale_pool: Optional[jnp.ndarray] = None,
                            v_scale_pool: Optional[jnp.ndarray] = None
                            ) -> jnp.ndarray:
-    """q (B, Hq, hd); k_pool/v_pool (n_pages, page, Hkv, hd);
+    """q (B, Hq, hd); k_pool/v_pool (n_pages, Hkv, page, hd);
     block_table (B, max_blocks); lengths (B,) -> (B, Hq, hd).
 
     Reads each slot's allocated pages in place through the block table
     (scalar-prefetch indirection) — no materialised virtual view.  With
-    scale pools (n_pages, page, Hkv) the pools hold int8 codes and the
-    kernel dequantises in-register inside its block loads.  Interpret
-    mode off-TPU."""
+    scale pools (n_pages, Hkv, page) the pools hold int8 codes and the
+    kernel applies the scales in-register inside its page sweep.
+    Interpret mode off-TPU."""
     interpret = jax.default_backend() != "tpu"
     return paged_decode_attention_pallas(q, k_pool, v_pool, block_table,
                                          lengths, k_scale_pool,

@@ -11,25 +11,28 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_pool: jnp.ndarray,
                                v_pool: jnp.ndarray,
                                block_table: jnp.ndarray,
                                lengths: jnp.ndarray) -> jnp.ndarray:
-    """q (B, Hq, hd); k_pool/v_pool (n_pages, page, Hkv, hd);
+    """q (B, Hq, hd); k_pool/v_pool (n_pages, Hkv, page, hd);
     block_table (B, max_blocks); lengths (B,) -> (B, Hq, hd) f32.
 
     A slot with ``lengths[b] == 0`` returns zeros (matching the kernel's
     free-lane contract)."""
     B, Hq, hd = q.shape
-    _, page, Hkv, _ = k_pool.shape
-    k_view = jnp.take(k_pool, block_table, axis=0).reshape(B, -1, Hkv, hd)
-    v_view = jnp.take(v_pool, block_table, axis=0).reshape(B, -1, Hkv, hd)
-    S = k_view.shape[1]
+    Hkv = k_pool.shape[1]
+    # (B, max_blocks, Hkv, page, hd) -> (B, Hkv, S, hd)
+    k_view = jnp.take(k_pool, block_table, axis=0).transpose(0, 2, 1, 3, 4)
+    v_view = jnp.take(v_pool, block_table, axis=0).transpose(0, 2, 1, 3, 4)
+    k_view = k_view.reshape(B, Hkv, -1, hd)
+    v_view = v_view.reshape(B, Hkv, -1, hd)
+    S = k_view.shape[2]
     G = Hq // Hkv
     qg = q.reshape(B, Hkv, G, hd).astype(jnp.float32)
-    scores = jnp.einsum("bkgh,bskh->bkgs", qg,
+    scores = jnp.einsum("bkgh,bksh->bkgs", qg,
                         k_view.astype(jnp.float32)) * (hd ** -0.5)
     mask = jnp.arange(S)[None, :] < lengths[:, None]
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     probs = jnp.where(mask[:, None, None, :], probs, 0.0)   # len-0 lanes
-    out = jnp.einsum("bkgs,bskh->bkgh", probs, v_view.astype(jnp.float32))
+    out = jnp.einsum("bkgs,bksh->bkgh", probs, v_view.astype(jnp.float32))
     return out.reshape(B, Hq, hd)
 
 

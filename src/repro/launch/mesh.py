@@ -13,13 +13,8 @@ import jax
 
 
 def _mk(shape, axes):
-    # axis_types / AxisType only exist on newer jax; Auto is the default
-    # there, so older versions just omit the argument
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

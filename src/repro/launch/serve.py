@@ -19,10 +19,30 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import floor as fl
-from repro.core.hardware import DEFAULT_CHIP
+from repro.core.hardware import chip_for_device_kind
+from repro.launch.compile_cache import place_compile_cache
 from repro.models.model import Model
 from repro.serving import DecodeEngine, SessionRequest
 from repro.training.data import DataLoader
+
+
+def device_label() -> str:
+    """The device JAX runs on, as every timing line names it."""
+    dev = jax.devices()[0]
+    return f"{dev.platform} {dev.device_kind}"
+
+
+def floor_note(cfg, ctx: int) -> str:
+    """The batch-1 analytic step floor of ``cfg`` at context ``ctx`` on
+    the device JAX runs on (peaks looked up by ``device_kind``; an
+    unknown TPU raises).  Off-TPU there is no device floor to compare a
+    wall time with."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return "device floor: not measured"
+    chip = chip_for_device_kind(dev.device_kind)
+    fc = fl.floor_cell(cfg, chip, ctx)
+    return f"{chip.name} analytic floor {fc.t_floor_ms:.2f} ms"
 
 
 def main():
@@ -196,6 +216,7 @@ def main():
     if args.paged:
         args.continuous = True
 
+    place_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -211,10 +232,7 @@ def main():
     if args.continuous:
         return serve_continuous(engine, cfg, args)
 
-    loader = DataLoader(cfg, batch=args.batch, seq_len=args.prompt_len,
-                        seed=args.seed)
-    batch = next(loader)
-    batch.pop("labels", None)
+    batch = prompt_batch(cfg, args.batch, args.prompt_len, args.seed)
     max_len = args.prompt_len + args.new_tokens + 1
 
     if args.mode == "fused":
@@ -227,14 +245,21 @@ def main():
                                        n_new=args.new_tokens,
                                        temperature=args.temperature,
                                        timed=args.timed)
-    print(f"generated {res.tokens.shape} tokens; {res.tokens_per_s:.1f} tok/s")
+    print(f"generated {res.tokens.shape} tokens; {res.tokens_per_s:.1f} tok/s "
+          f"on {device_label()}")
     if args.timed and res.step_times_s:
         import numpy as np
         p50 = float(np.median(res.step_times_s)) * 1e3
-        fc = fl.floor_cell(cfg, DEFAULT_CHIP, args.prompt_len)
-        print(f"p50 step {p50:.2f} ms (v5e analytic floor for the FULL "
-              f"config would be {fc.t_floor_ms:.2f} ms)")
+        print(f"p50 step {p50:.2f} ms on {device_label()} "
+              f"({floor_note(cfg, args.prompt_len)})")
     print("first tokens:", res.tokens[0, :12].tolist())
+
+
+def prompt_batch(cfg, batch: int, prompt_len: int, seed: int):
+    """Seeded synthetic prompts (batch, prompt_len) for streamed decode."""
+    out = next(DataLoader(cfg, batch=batch, seq_len=prompt_len, seed=seed))
+    out.pop("labels", None)
+    return out
 
 
 def mixed_requests(cfg, n_sessions: int, *, base_prompt: int,
@@ -379,7 +404,7 @@ def serve_continuous(engine: DecodeEngine, cfg, args):
     print(f"served {len(res.sessions)} sessions through {args.slots} slots "
           f"({args.dispatch}, {layout}, attn={backend}): {n_tok} tokens in "
           f"{res.ticks} ticks / {res.dispatches} decode dispatches, "
-          f"{res.tokens_per_s:.1f} tok/s aggregate")
+          f"{res.tokens_per_s:.1f} tok/s aggregate on {device_label()}")
     if args.steps_per_tick > 1:
         dec_tok = n_tok - len(res.sessions)   # first tokens come from prefill
         print(f"horizon-K: steps_per_tick={args.steps_per_tick}, "

@@ -399,13 +399,16 @@ def attention_decode(p: Params, x: jnp.ndarray, k_cache: jnp.ndarray,
 def paged_view(pool: jnp.ndarray, block_table: jnp.ndarray) -> jnp.ndarray:
     """Gather a slot-major contiguous K/V view out of a page pool.
 
-    pool (n_pages, page_size, Hkv, hd); block_table (B, max_blocks) of
-    page indices -> (B, max_blocks * page_size, Hkv, hd).  Every slot's
-    view has the same (constant) virtual length, so the decode step stays
-    ONE compiled program; which physical pages back it is pure data."""
+    pool (n_pages, Hkv, page_size, hd) — or a scale pool (n_pages, Hkv,
+    page_size); block_table (B, max_blocks) of page indices ->
+    (B, max_blocks * page_size, Hkv, hd) (resp. (B, S, Hkv)).  Every
+    slot's view has the same (constant) virtual length, so the decode
+    step stays ONE compiled program; which physical pages back it is
+    pure data."""
     B, max_blocks = block_table.shape
-    pages = jnp.take(pool, block_table, axis=0)
-    return pages.reshape(B, max_blocks * pool.shape[1], *pool.shape[2:])
+    Hkv, page = pool.shape[1:3]
+    pages = jnp.swapaxes(jnp.take(pool, block_table, axis=0), 2, 3)
+    return pages.reshape(B, max_blocks * page, Hkv, *pool.shape[3:])
 
 
 def attention_decode_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
@@ -417,7 +420,7 @@ def attention_decode_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
                            v_scale_pool=None):
     """One-token decode through a paged KV cache.
 
-    x (B,1,D); k_pool/v_pool (n_pages, page_size, Hkv, hd);
+    x (B,1,D); k_pool/v_pool (n_pages, Hkv, page_size, hd);
     block_table (B, max_blocks); pos (B,) absolute per-slot positions.
     The new K/V row is scattered into the slot's current page
     (``block_table[b, pos[b] // page_size]`` at offset
@@ -433,7 +436,7 @@ def attention_decode_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
     stop touching their allocated pages).  Returns
     (out, new_k_pool, new_v_pool[, new_k_scale_pool, new_v_scale_pool]).
 
-    k_scale_pool/v_scale_pool (n_pages, page_size, Hkv) switch the pool
+    k_scale_pool/v_scale_pool (n_pages, Hkv, page_size) switch the pool
     to the int8-quantised layout: the new row is quantised on write
     (codes into k_pool, per-head scale into k_scale_pool, same page/off
     — the scale pools share the block table), and reads dequantise per
@@ -449,7 +452,7 @@ def attention_decode_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
     q, k_new, v_new = _project_qkv(p, x, cfg)
     q = apply_rope_fn(q, angles)
     k_new = apply_rope_fn(k_new, angles)
-    page_size = k_pool.shape[1]
+    page_size = k_pool.shape[2]
     page = jnp.take_along_axis(block_table, (pos // page_size)[:, None],
                                axis=1)[:, 0]
     if active is not None:
@@ -460,10 +463,10 @@ def attention_decode_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
         from repro.quant import kv as kvq
         k_new, ks = kvq.quantize_kv_write(k_new)
         v_new, vs = kvq.quantize_kv_write(v_new)
-        k_scale_pool = k_scale_pool.at[page, off].set(ks[:, 0])
-        v_scale_pool = v_scale_pool.at[page, off].set(vs[:, 0])
-    k_pool = k_pool.at[page, off].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[page, off].set(v_new[:, 0].astype(v_pool.dtype))
+        k_scale_pool = k_scale_pool.at[page, :, off].set(ks[:, 0])
+        v_scale_pool = v_scale_pool.at[page, :, off].set(vs[:, 0])
+    k_pool = k_pool.at[page, :, off].set(k_new[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[page, :, off].set(v_new[:, 0].astype(v_pool.dtype))
     # routing (fused in-place kernel vs gathered-view reference) lives in
     # _decode_attend; a slot's live length is pos+1 (the row just
     # written), matching decode_mask(pos, ...) exactly
@@ -499,17 +502,17 @@ def attention_prefill_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
     (chunked == whole-prompt stays exact).  Returns
     (out (1, C, D), new_k_pool, new_v_pool[, new scale pools])."""
     _, C, _ = x.shape
-    page_size = k_pool.shape[1]
+    page_size = k_pool.shape[2]
     q, k_new, v_new = _project_qkv(p, x, cfg)
     q = apply_rope_fn(q, angles)
     k_new = apply_rope_fn(k_new, angles)
     n_chunk_pages = -(-C // page_size)
     pad = n_chunk_pages * page_size - C
 
-    def to_pages(t, dtype):   # (1, C, ...) -> (n_pages_c, page, ...)
+    def to_pages(t, dtype):   # (1, C, Hkv, ...) -> (n_pages_c, Hkv, page, ...)
         t = jnp.pad(t[0], ((0, pad),) + ((0, 0),) * (t.ndim - 2))
-        return t.reshape((n_chunk_pages, page_size)
-                         + t.shape[1:]).astype(dtype)
+        t = t.reshape((n_chunk_pages, page_size) + t.shape[1:])
+        return jnp.swapaxes(t, 1, 2).astype(dtype)
 
     first = start_pos // page_size
     idx = jax.lax.dynamic_slice_in_dim(slot_pages, first, n_chunk_pages)

@@ -299,7 +299,7 @@ class Model:
 
         ``paged=True`` (implies slotted) replaces the per-slot
         ``max_len`` K/V rows with a **page pool** plus a per-slot block
-        table: ``k``/``v`` become (L, n_pages, page_size, Hkv, hd) and
+        table: ``k``/``v`` become (L, n_pages, Hkv, page_size, hd) and
         ``block_table`` (batch, max_blocks) maps each slot's virtual
         positions onto pool pages.  Page 0 is the reserved garbage
         sentinel (never allocated; free lanes point at it).  With
@@ -315,7 +315,7 @@ class Model:
         ``kv_quant="int8"`` (equivalently ``kv_dtype=jnp.int8``) stores
         K/V as int8 codes with per-(token, head) float32 scales.  On
         paged caches the scales ride parallel ``k_scale``/``v_scale``
-        pools of shape (L, n_pages, page_size, Hkv) sharing the block
+        pools of shape (L, n_pages, Hkv, page_size) sharing the block
         table, so a page id addresses codes and scales together —
         allocation, CoW, tiering, and prefix sharing all work unchanged
         page-at-a-time."""
@@ -341,8 +341,10 @@ class Model:
             if n_pages is None:
                 n_pages = 1 + batch_size * max_blocks   # full backing
             assert n_pages >= 2, "need the garbage page plus >=1 real page"
-            shape = (cfg.n_layers, n_pages, page_size,
-                     cfg.n_kv_heads, cfg.head_dim)
+            # head-major pages: the fused kernel's block is one page of
+            # every head, tiled on its (page_size, hd) minor dims
+            shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size,
+                     cfg.head_dim)
             cache = {"k": jnp.zeros(shape, kv_dtype),
                      "v": jnp.zeros(shape, kv_dtype),
                      "pos": jnp.zeros((batch_size,), jnp.int32),
@@ -691,7 +693,7 @@ class Model:
                 # virtual per-slot length = block-table span; the write
                 # position is resolved through the block table inside
                 # attention_decode_paged
-                kv_len = cache["block_table"].shape[1] * cache["k"].shape[2]
+                kv_len = cache["block_table"].shape[1] * cache["k"].shape[3]
                 ring, write_pos = False, pos
             else:
                 kv_len = cache["k"].shape[2]

@@ -26,6 +26,7 @@ from repro.configs.base import ArchConfig
 from repro.core.dispatch import StepProgram
 from repro.models.model import Model
 from repro.quant import quantize_tree
+from repro.serving.programs import jit_cache_size
 from repro.serving.sampling import sample
 
 
@@ -56,6 +57,11 @@ class DecodeEngine:
             donate_argnums=donate)
 
     # -------------------------------------------------------------- API
+    def step_cache_size(self) -> int:
+        """Executables compiled for the streamed decode step (the
+        recompile guard: 1 for a run at one batch and cache length)."""
+        return jit_cache_size(self._step)
+
     def new_cache(self, batch: int, max_len: int):
         return self.model.init_cache(batch, max_len, kv_dtype=self.kv_dtype)
 

@@ -24,18 +24,12 @@ import jax
 from repro.models.model import Model
 
 
-def jit_cache_size(fn) -> Optional[int]:
-    """Compiled-executable count of a ``jax.jit`` callable.
-
-    ``_cache_size()`` is a private jax internal (the only hook that
-    exposes the per-callable executable cache today); wrap it so a jax
-    upgrade that renames it degrades the recompile guard to ``None``
-    (= "unknown") instead of crashing the scheduler.
-    """
-    try:
-        return fn._cache_size()
-    except Exception:
-        return None
+def jit_cache_size(fn) -> int:
+    """Compiled-executable count of a ``jax.jit`` callable (the
+    recompile guard).  ``_cache_size()`` is the per-callable executable
+    cache's own count; a jax without it fails here, loudly, rather than
+    leaving the guard blind."""
+    return fn._cache_size()
 
 
 class SchedulerPrograms:

@@ -39,8 +39,8 @@ TABLE10_PAGE_SIZES = (4, 8, 16)
 
 def _rand_pool(key, n_pages, page, Hkv, hd, dtype=jnp.float32):
     ks = jax.random.split(key, 2)
-    k_pool = jax.random.normal(ks[0], (n_pages, page, Hkv, hd), dtype)
-    v_pool = jax.random.normal(ks[1], (n_pages, page, Hkv, hd), dtype)
+    k_pool = jax.random.normal(ks[0], (n_pages, Hkv, page, hd), dtype)
+    v_pool = jax.random.normal(ks[1], (n_pages, Hkv, page, hd), dtype)
     return k_pool, v_pool
 
 

@@ -65,7 +65,7 @@ class TestPagedCache:
     def test_layout(self):
         m = Model(CFG)
         cache = m.init_cache(3, 32, paged=True, page_size=8)
-        L, n_pages, page, hkv, hd = cache["k"].shape
+        L, n_pages, hkv, page, hd = cache["k"].shape
         assert (L, page, hkv, hd) == (CFG.n_layers, 8, CFG.n_kv_heads,
                                       CFG.head_dim)
         assert n_pages == 1 + 3 * 4            # garbage + full backing
@@ -105,7 +105,8 @@ class TestPagedCache:
             m.step_program(params, cache)
 
     def test_paged_view_gathers_block_table(self):
-        pool = jnp.arange(5 * 2 * 1 * 1, dtype=jnp.float32).reshape(5, 2, 1, 1)
+        # (n_pages, Hkv, page, hd): page p holds tokens 2p, 2p + 1
+        pool = jnp.arange(5 * 1 * 2 * 1, dtype=jnp.float32).reshape(5, 1, 2, 1)
         bt = jnp.array([[3, 1], [0, 0]], jnp.int32)
         view = np.asarray(attn.paged_view(pool, bt))
         assert view.shape == (2, 4, 1, 1)
@@ -312,14 +313,18 @@ class TestPagedSchedulerInvariants:
 
 
 class TestJitCacheSize:
-    """The recompile guard must not crash on jax versions that drop the
-    private ``_cache_size`` hook — it degrades to None (= unknown)."""
+    """The recompile guard counts one executable per input signature."""
 
     def test_counts_compiled_executables(self):
         f = jax.jit(lambda x: x + 1)
         f(jnp.ones((2,)))
-        assert jit_cache_size(f) in (1, None)
+        f(jnp.zeros((2,)))
+        assert jit_cache_size(f) == 1
 
     def test_degrades_to_none_without_the_hook(self):
-        assert jit_cache_size(object()) is None
-        assert jit_cache_size(lambda x: x) is None
+        """A second input shape is a second executable: the count is
+        exact, never None."""
+        f = jax.jit(lambda x: x + 1)
+        f(jnp.ones((2,)))
+        f(jnp.ones((3,)))
+        assert jit_cache_size(f) == 2

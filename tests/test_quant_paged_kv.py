@@ -95,13 +95,13 @@ class TestGarbageSentinel:
         k = jax.random.PRNGKey(3)
         n_pages, page, Hkv, hd, B = 5, 4, 2, 16, 2
         ks = [jax.random.fold_in(k, i) for i in range(5)]
-        k_pool = jax.random.randint(ks[0], (n_pages, page, Hkv, hd),
+        k_pool = jax.random.randint(ks[0], (n_pages, Hkv, page, hd),
                                     -127, 128, jnp.int32).astype(jnp.int8)
-        v_pool = jax.random.randint(ks[1], (n_pages, page, Hkv, hd),
+        v_pool = jax.random.randint(ks[1], (n_pages, Hkv, page, hd),
                                     -127, 128, jnp.int32).astype(jnp.int8)
-        k_sc = jax.random.uniform(ks[2], (n_pages, page, Hkv),
+        k_sc = jax.random.uniform(ks[2], (n_pages, Hkv, page),
                                   jnp.float32, 0.01, 0.1)
-        v_sc = jax.random.uniform(ks[3], (n_pages, page, Hkv),
+        v_sc = jax.random.uniform(ks[3], (n_pages, Hkv, page),
                                   jnp.float32, 0.01, 0.1)
         if poison:     # worst representable content, finite on purpose
             k_pool = k_pool.at[GARBAGE_PAGE].set(127)
