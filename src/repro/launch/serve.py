@@ -252,7 +252,16 @@ def main():
         p50 = float(np.median(res.step_times_s)) * 1e3
         print(f"p50 step {p50:.2f} ms on {device_label()} "
               f"({floor_note(cfg, args.prompt_len)})")
+        print(phase_line(engine.phases.totals))
     print("first tokens:", res.tokens[0, :12].tolist())
+
+
+def phase_line(totals) -> str:
+    """The host's own time per phase (``core/spans.py``), phases that
+    ran only."""
+    parts = [f"{name} {sec * 1e3:.1f}" for name, sec in totals.items()
+             if sec > 0]
+    return "host ms by phase: " + (", ".join(parts) or "none")
 
 
 def prompt_batch(cfg, batch: int, prompt_len: int, seed: int):
@@ -408,9 +417,7 @@ def serve_continuous(engine: DecodeEngine, cfg, args):
     if args.steps_per_tick > 1:
         dec_tok = n_tok - len(res.sessions)   # first tokens come from prefill
         print(f"horizon-K: steps_per_tick={args.steps_per_tick}, "
-              f"{dec_tok / max(res.dispatches, 1):.1f} tokens per dispatch, "
-              f"host dispatch {res.host_dispatch_s * 1e3:.1f} ms + sync "
-              f"{res.host_sync_s * 1e3:.1f} ms over the run")
+              f"{dec_tok / max(res.dispatches, 1):.1f} tokens per dispatch")
     if args.paged:
         max_blocks = -(-max_len // args.page_size)
         full = 1 + args.slots * max_blocks
@@ -468,6 +475,7 @@ def serve_continuous(engine: DecodeEngine, cfg, args):
     print(f"decode step {compiled}, "
           f"{res.launches_per_step} host launch(es) per step")
     if args.timed:
+        print(phase_line(res.host_phase_s))
         for sid, s in res.sessions.items():
             if not s.step_times_s:
                 continue

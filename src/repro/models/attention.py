@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core.spans import scope
 from repro.launch import hints
 from repro.models.common import dense_init
 
@@ -118,19 +119,23 @@ def attention_full(p: Params, x: jnp.ndarray, angles: jnp.ndarray,
     lax.scan — exact math, (bq, S) score footprint instead of (S, S)."""
     from repro.quant.paths import matmul
     B, S, _ = x.shape
-    q, k, v = _project_qkv(p, x, cfg)
-    q = apply_rope_fn(q, angles)
-    k = apply_rope_fn(k, angles)
+    with scope("qkv"):
+        q, k, v = _project_qkv(p, x, cfg)
+        q = apply_rope_fn(q, angles)
+        k = apply_rope_fn(k, angles)
 
     if S <= CHUNKED_ATTN_THRESHOLD:
-        # prefer kv-head TP; context-parallel (query-seq) fallback for
-        # head counts that don't divide the model axis
-        scores = hints.constrain_first_fit(
-            _gqa_scores(q, k, cfg),
-            [("dp", "tp"), ("dp", None, None, "tp")])
-        probs = _causal_probs(scores, jnp.int32(0), S, cfg.sliding_window)
-        out = _gqa_out(probs, v, cfg).astype(x.dtype)
-        return matmul(out, p["wo"]), (k, v)
+        with scope("attend"):
+            # prefer kv-head TP; context-parallel (query-seq) fallback
+            # for head counts that don't divide the model axis
+            scores = hints.constrain_first_fit(
+                _gqa_scores(q, k, cfg),
+                [("dp", "tp"), ("dp", None, None, "tp")])
+            probs = _causal_probs(scores, jnp.int32(0), S,
+                                  cfg.sliding_window)
+            out = _gqa_out(probs, v, cfg).astype(x.dtype)
+        with scope("attn_out"):
+            return matmul(out, p["wo"]), (k, v)
 
     bq = CHUNK_Q
     assert S % bq == 0, (S, bq)
@@ -147,10 +152,13 @@ def attention_full(p: Params, x: jnp.ndarray, angles: jnp.ndarray,
     # chunk body is always rematted: the (bq, S) score tile is recomputed
     # in backward instead of saved — flash-attention residual behaviour
     body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
-    _, blocks = jax.lax.scan(
-        body, None, (jnp.arange(S // bq), jnp.moveaxis(qb, 1, 0)))
-    out = jnp.moveaxis(blocks, 0, 1).reshape(B, S, cfg.n_heads * cfg.head_dim)
-    return matmul(out, p["wo"]), (k, v)
+    with scope("attend"):
+        _, blocks = jax.lax.scan(
+            body, None, (jnp.arange(S // bq), jnp.moveaxis(qb, 1, 0)))
+        out = jnp.moveaxis(blocks, 0, 1).reshape(
+            B, S, cfg.n_heads * cfg.head_dim)
+    with scope("attn_out"):
+        return matmul(out, p["wo"]), (k, v)
 
 
 # --------------------------------------------------------------------------
@@ -361,32 +369,33 @@ def attention_decode(p: Params, x: jnp.ndarray, k_cache: jnp.ndarray,
 
     Returns (out, new_k, new_v[, new_k_scale, new_v_scale])."""
     from repro.quant import kv as kvq
-    B, S1, _ = x.shape
-    q, k_new, v_new = _project_qkv(p, x, cfg)
-    q = apply_rope_fn(q, angles)
-    k_new = apply_rope_fn(k_new, angles)
+    with scope("qkv"):
+        q, k_new, v_new = _project_qkv(p, x, cfg)
+        q = apply_rope_fn(q, angles)
+        k_new = apply_rope_fn(k_new, angles)
     quantized = k_scale is not None
-    if quantized:
-        kq, ks = kvq.quantize_kv_write(k_new)
-        vq, vs = kvq.quantize_kv_write(v_new)
-        k_cache = _kv_write(k_cache, kq, write_pos, active)
-        v_cache = _kv_write(v_cache, vq, write_pos, active)
-        k_scale = _kv_write(k_scale, ks, write_pos, active)
-        v_scale = _kv_write(v_scale, vs, write_pos, active)
+    with scope("kv_write"):
+        if quantized:
+            kq, ks = kvq.quantize_kv_write(k_new)
+            vq, vs = kvq.quantize_kv_write(v_new)
+            k_cache = _kv_write(k_cache, kq, write_pos, active)
+            v_cache = _kv_write(v_cache, vq, write_pos, active)
+            k_scale = _kv_write(k_scale, ks, write_pos, active)
+            v_scale = _kv_write(v_scale, vs, write_pos, active)
+        else:
+            k_cache = _kv_write(k_cache, k_new, write_pos, active)
+            v_cache = _kv_write(v_cache, v_new, write_pos, active)
+    with scope("attend"):
         k_read, v_read = k_cache, v_cache    # sdpa folds scales; others
-        if backend != "sdpa":                # take a dequantised view
+        if quantized and backend != "sdpa":  # take a dequantised view
             k_read = kvq.dequantize_kv(k_cache, k_scale, x.dtype)
             v_read = kvq.dequantize_kv(v_cache, v_scale, x.dtype)
-    else:
-        k_cache = _kv_write(k_cache, k_new, write_pos, active)
-        v_cache = _kv_write(v_cache, v_new, write_pos, active)
-        k_read, v_read = k_cache, v_cache
-
-    out = _decode_attend(q, k_read, v_read, mask, cfg, backend, x.dtype,
-                         k_scale=k_scale if quantized else None,
-                         v_scale=v_scale if quantized else None)
+        out = _decode_attend(q, k_read, v_read, mask, cfg, backend, x.dtype,
+                             k_scale=k_scale if quantized else None,
+                             v_scale=v_scale if quantized else None)
     from repro.quant.paths import matmul
-    out = matmul(out, p["wo"])
+    with scope("attn_out"):
+        out = matmul(out, p["wo"])
     if quantized:
         return out, k_cache, v_cache, k_scale, v_scale
     return out, k_cache, v_cache
@@ -449,34 +458,38 @@ def attention_decode_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
     *allocated* pages instead of 3x the constant virtual view.  Every
     other backend takes the gather+SDPA reference route through the
     materialised ``paged_view``."""
-    q, k_new, v_new = _project_qkv(p, x, cfg)
-    q = apply_rope_fn(q, angles)
-    k_new = apply_rope_fn(k_new, angles)
+    with scope("qkv"):
+        q, k_new, v_new = _project_qkv(p, x, cfg)
+        q = apply_rope_fn(q, angles)
+        k_new = apply_rope_fn(k_new, angles)
     page_size = k_pool.shape[2]
-    page = jnp.take_along_axis(block_table, (pos // page_size)[:, None],
-                               axis=1)[:, 0]
-    if active is not None:
-        page = jnp.where(active, page, 0)   # 0 = reserved garbage page
-    off = pos % page_size
     quantized = k_scale_pool is not None
-    if quantized:
-        from repro.quant import kv as kvq
-        k_new, ks = kvq.quantize_kv_write(k_new)
-        v_new, vs = kvq.quantize_kv_write(v_new)
-        k_scale_pool = k_scale_pool.at[page, :, off].set(ks[:, 0])
-        v_scale_pool = v_scale_pool.at[page, :, off].set(vs[:, 0])
-    k_pool = k_pool.at[page, :, off].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[page, :, off].set(v_new[:, 0].astype(v_pool.dtype))
-    # routing (fused in-place kernel vs gathered-view reference) lives in
-    # _decode_attend; a slot's live length is pos+1 (the row just
-    # written), matching decode_mask(pos, ...) exactly
-    paged = (k_pool, v_pool, block_table, pos + 1)
-    if quantized:
-        paged = paged + (k_scale_pool, v_scale_pool)
-    out = _decode_attend(q, None, None, mask, cfg, backend, x.dtype,
-                         paged=paged)
+    with scope("kv_write"):
+        page = jnp.take_along_axis(block_table, (pos // page_size)[:, None],
+                                   axis=1)[:, 0]
+        if active is not None:
+            page = jnp.where(active, page, 0)   # 0 = reserved garbage page
+        off = pos % page_size
+        if quantized:
+            from repro.quant import kv as kvq
+            k_new, ks = kvq.quantize_kv_write(k_new)
+            v_new, vs = kvq.quantize_kv_write(v_new)
+            k_scale_pool = k_scale_pool.at[page, :, off].set(ks[:, 0])
+            v_scale_pool = v_scale_pool.at[page, :, off].set(vs[:, 0])
+        k_pool = k_pool.at[page, :, off].set(k_new[:, 0].astype(k_pool.dtype))
+        v_pool = v_pool.at[page, :, off].set(v_new[:, 0].astype(v_pool.dtype))
+    with scope("attend"):
+        # routing (fused in-place kernel vs gathered-view reference)
+        # lives in _decode_attend; a slot's live length is pos+1 (the
+        # row just written), matching decode_mask(pos, ...) exactly
+        paged = (k_pool, v_pool, block_table, pos + 1)
+        if quantized:
+            paged = paged + (k_scale_pool, v_scale_pool)
+        out = _decode_attend(q, None, None, mask, cfg, backend, x.dtype,
+                             paged=paged)
     from repro.quant.paths import matmul
-    out = matmul(out, p["wo"])
+    with scope("attn_out"):
+        out = matmul(out, p["wo"])
     if quantized:
         return out, k_pool, v_pool, k_scale_pool, v_scale_pool
     return out, k_pool, v_pool
@@ -503,9 +516,10 @@ def attention_prefill_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
     (out (1, C, D), new_k_pool, new_v_pool[, new scale pools])."""
     _, C, _ = x.shape
     page_size = k_pool.shape[2]
-    q, k_new, v_new = _project_qkv(p, x, cfg)
-    q = apply_rope_fn(q, angles)
-    k_new = apply_rope_fn(k_new, angles)
+    with scope("qkv"):
+        q, k_new, v_new = _project_qkv(p, x, cfg)
+        q = apply_rope_fn(q, angles)
+        k_new = apply_rope_fn(k_new, angles)
     n_chunk_pages = -(-C // page_size)
     pad = n_chunk_pages * page_size - C
 
@@ -514,37 +528,41 @@ def attention_prefill_paged(p: Params, x: jnp.ndarray, k_pool: jnp.ndarray,
         t = t.reshape((n_chunk_pages, page_size) + t.shape[1:])
         return jnp.swapaxes(t, 1, 2).astype(dtype)
 
-    first = start_pos // page_size
-    idx = jax.lax.dynamic_slice_in_dim(slot_pages, first, n_chunk_pages)
     quantized = k_scale_pool is not None
     if quantized:
         from repro.quant import kv as kvq
-        k_new, ks = kvq.quantize_kv_write(k_new)
-        v_new, vs = kvq.quantize_kv_write(v_new)
-        k_scale_pool = k_scale_pool.at[idx].set(to_pages(ks, jnp.float32))
-        v_scale_pool = v_scale_pool.at[idx].set(to_pages(vs, jnp.float32))
-    k_pool = k_pool.at[idx].set(to_pages(k_new, k_pool.dtype))
-    v_pool = v_pool.at[idx].set(to_pages(v_new, v_pool.dtype))
-    if quantized:
-        from repro.quant import kv as kvq
-        k_view = kvq.dequantize_kv(paged_view(k_pool, slot_pages[None, :]),
-                                   paged_view(k_scale_pool,
-                                              slot_pages[None, :]), x.dtype)
-        v_view = kvq.dequantize_kv(paged_view(v_pool, slot_pages[None, :]),
-                                   paged_view(v_scale_pool,
-                                              slot_pages[None, :]), x.dtype)
-    else:
-        k_view = paged_view(k_pool, slot_pages[None, :])
-        v_view = paged_view(v_pool, slot_pages[None, :])
-    virtual = k_view.shape[1]
-    qpos = start_pos + jnp.arange(C)
-    mask = jnp.arange(virtual)[None, :] <= qpos[:, None]      # (C, virtual)
-    scores = _gqa_scores(q, k_view.astype(q.dtype), cfg)      # (1,K,G,C,virt)
-    scores = jnp.where(mask[None, None, None, :, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = _gqa_out(probs, v_view.astype(q.dtype), cfg).astype(x.dtype)
+    with scope("kv_write"):
+        first = start_pos // page_size
+        idx = jax.lax.dynamic_slice_in_dim(slot_pages, first, n_chunk_pages)
+        if quantized:
+            k_new, ks = kvq.quantize_kv_write(k_new)
+            v_new, vs = kvq.quantize_kv_write(v_new)
+            k_scale_pool = k_scale_pool.at[idx].set(to_pages(ks, jnp.float32))
+            v_scale_pool = v_scale_pool.at[idx].set(to_pages(vs, jnp.float32))
+        k_pool = k_pool.at[idx].set(to_pages(k_new, k_pool.dtype))
+        v_pool = v_pool.at[idx].set(to_pages(v_new, v_pool.dtype))
+    with scope("attend"):
+        rows = slot_pages[None, :]
+        if quantized:
+            k_view = kvq.dequantize_kv(paged_view(k_pool, rows),
+                                       paged_view(k_scale_pool, rows),
+                                       x.dtype)
+            v_view = kvq.dequantize_kv(paged_view(v_pool, rows),
+                                       paged_view(v_scale_pool, rows),
+                                       x.dtype)
+        else:
+            k_view = paged_view(k_pool, rows)
+            v_view = paged_view(v_pool, rows)
+        virtual = k_view.shape[1]
+        qpos = start_pos + jnp.arange(C)
+        mask = jnp.arange(virtual)[None, :] <= qpos[:, None]  # (C, virtual)
+        scores = _gqa_scores(q, k_view.astype(q.dtype), cfg)  # (1,K,G,C,virt)
+        scores = jnp.where(mask[None, None, None, :, :], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = _gqa_out(probs, v_view.astype(q.dtype), cfg).astype(x.dtype)
     from repro.quant.paths import matmul
-    out = matmul(out, p["wo"])
+    with scope("attn_out"):
+        out = matmul(out, p["wo"])
     if quantized:
         return out, k_pool, v_pool, k_scale_pool, v_scale_pool
     return out, k_pool, v_pool

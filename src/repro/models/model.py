@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.core.dispatch import StepProgram
+from repro.core.spans import scope
 from repro.models import attention as attn
 from repro.models import mamba2, moe
 from repro.models.common import (apply_norm, apply_rope, cross_entropy,
@@ -144,17 +145,31 @@ class Model:
     # ------------------------------------------------------------------
     # blocks: full-sequence
     # ------------------------------------------------------------------
-    def _attn_block_full(self, bp: Params, x, angles):
+    def _mlp_block(self, bp: Params, x):
+        """norm2, the MLP (or MoE) and the residual add: ``(x, aux)``."""
         cfg = self.cfg
-        a_out, (k, v) = attn.attention_full(bp["attn"], apply_norm(x, bp["norm1"]),
-                                            angles, cfg, apply_rope)
-        x = x + a_out
-        h = apply_norm(x, bp["norm2"])
-        if cfg.family == "moe":
-            m_out, aux = moe.moe_forward(bp["moe"], h, cfg)
-        else:
-            m_out, aux = mlp_forward(bp["mlp"], h, cfg.mlp_gated), 0.0
-        return x + m_out, aux, (k, v)
+        with scope("mlp"):
+            h = apply_norm(x, bp["norm2"])
+            if cfg.family == "moe":
+                m_out, aux = moe.moe_forward(bp["moe"], h, cfg)
+            else:
+                m_out, aux = mlp_forward(bp["mlp"], h, cfg.mlp_gated), 0.0
+            return x + m_out, aux
+
+    def _head(self, params: Params, x):
+        """Final norm and logits."""
+        with scope("head"):
+            return self.lm_logits(params, apply_norm(x, params["final_norm"]))
+
+    def _attn_block_full(self, bp: Params, x, angles):
+        with scope("qkv"):
+            h = apply_norm(x, bp["norm1"])
+        a_out, (k, v) = attn.attention_full(bp["attn"], h, angles, self.cfg,
+                                            apply_rope)
+        with scope("attn_out"):
+            x = x + a_out
+        x, aux = self._mlp_block(bp, x)
+        return x, aux, (k, v)
 
     def _mamba_block_full(self, bp: Params, x):
         y, h_fin, conv = mamba2.mamba_forward(
@@ -179,7 +194,8 @@ class Model:
         x = batch.get("embeds")
         tokens = batch.get("tokens")
         if x is None:
-            x = self.embed_tokens(params, tokens)
+            with scope("embed"):
+                x = self.embed_tokens(params, tokens)
         elif tokens is not None and cfg.family == "vlm":
             # merged stream: embeds already contain patch + text embeddings
             pass
@@ -272,8 +288,7 @@ class Model:
 
     def forward(self, params: Params, batch: Dict) -> Tuple[jnp.ndarray, jnp.ndarray]:
         x, aux, _ = self.backbone(params, batch)
-        x = apply_norm(x, params["final_norm"])
-        return self.lm_logits(params, x), aux
+        return self._head(params, x), aux
 
     def loss(self, params: Params, batch: Dict, *, aux_weight: float = 0.01,
              z_loss: float = 0.0) -> Tuple[jnp.ndarray, Dict]:
@@ -471,31 +486,31 @@ class Model:
 
         quantized_kv = "k_scale" in cache
 
-        if cfg.family in ("dense", "vlm", "audio", "moe"):
-            k, v = caches    # (L, B, S, Hkv, hd) stacked by scan
-            if quantized_kv:
-                from repro.quant import kv as kvq
-                kq, ks = kvq.quantize_kv_write(k)
-                vq, vs = kvq.quantize_kv_write(v)
-                cache = dict(cache,
-                             k=place(kq, cache["k"], pre=lambda t: t),
-                             v=place(vq, cache["v"], pre=lambda t: t),
-                             k_scale=place(ks[..., None], cache["k_scale"][..., None],
-                                           pre=lambda t: t.astype(jnp.float32))[..., 0],
-                             v_scale=place(vs[..., None], cache["v_scale"][..., None],
-                                           pre=lambda t: t.astype(jnp.float32))[..., 0])
-            else:
-                cache = dict(cache, k=place(k, cache["k"]), v=place(v, cache["v"]))
-        elif cfg.family == "ssm":
-            h, conv = caches
-            cache = dict(cache, h=h, conv=conv.astype(cache["conv"].dtype))
-        else:  # hybrid
-            (h, conv), (ks, vs) = caches
-            cache = dict(cache, h=h, conv=conv.astype(cache["conv"].dtype),
-                         k=place(ks, cache["k"]), v=place(vs, cache["v"]))
+        with scope("kv_write"):
+            if cfg.family in ("dense", "vlm", "audio", "moe"):
+                k, v = caches    # (L, B, S, Hkv, hd) stacked by scan
+                if quantized_kv:
+                    from repro.quant import kv as kvq
+                    kq, ks = kvq.quantize_kv_write(k)
+                    vq, vs = kvq.quantize_kv_write(v)
+                    cache = dict(cache,
+                                 k=place(kq, cache["k"], pre=lambda t: t),
+                                 v=place(vq, cache["v"], pre=lambda t: t),
+                                 k_scale=place(ks[..., None], cache["k_scale"][..., None],
+                                               pre=lambda t: t.astype(jnp.float32))[..., 0],
+                                 v_scale=place(vs[..., None], cache["v_scale"][..., None],
+                                               pre=lambda t: t.astype(jnp.float32))[..., 0])
+                else:
+                    cache = dict(cache, k=place(k, cache["k"]), v=place(v, cache["v"]))
+            elif cfg.family == "ssm":
+                h, conv = caches
+                cache = dict(cache, h=h, conv=conv.astype(cache["conv"].dtype))
+            else:  # hybrid
+                (h, conv), (ks, vs) = caches
+                cache = dict(cache, h=h, conv=conv.astype(cache["conv"].dtype),
+                             k=place(ks, cache["k"]), v=place(vs, cache["v"]))
         cache["pos"] = jnp.asarray(S, jnp.int32)
-        x_last = apply_norm(x[:, -1:], params["final_norm"])
-        return self.lm_logits(params, x_last), cache
+        return self._head(params, x[:, -1:]), cache
 
     def prefill_into_slot(self, params: Params, batch: Dict, cache: Cache,
                           slot: jnp.ndarray) -> Tuple[jnp.ndarray, Cache]:
@@ -527,23 +542,23 @@ class Model:
         zero = jnp.int32(0)
         start = (zero, jnp.asarray(slot, jnp.int32), zero, zero, zero)
         updates: Cache = {"pos": cache["pos"].at[slot].set(S)}
-        if "k_scale" in cache:
-            from repro.quant import kv as kvq
-            k, ks = kvq.quantize_kv_write(k)
-            v, vs = kvq.quantize_kv_write(v)
+        with scope("kv_write"):
+            if "k_scale" in cache:
+                from repro.quant import kv as kvq
+                k, ks = kvq.quantize_kv_write(k)
+                v, vs = kvq.quantize_kv_write(v)
+                updates.update(
+                    k_scale=jax.lax.dynamic_update_slice(
+                        cache["k_scale"], ks, start[:-1]),
+                    v_scale=jax.lax.dynamic_update_slice(
+                        cache["v_scale"], vs, start[:-1]))
             updates.update(
-                k_scale=jax.lax.dynamic_update_slice(
-                    cache["k_scale"], ks, start[:-1]),
-                v_scale=jax.lax.dynamic_update_slice(
-                    cache["v_scale"], vs, start[:-1]))
-        updates.update(
-            k=jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), start),
-            v=jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), start))
+                k=jax.lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype), start),
+                v=jax.lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype), start))
         cache = dict(cache, **updates)
-        x_last = apply_norm(x[:, -1:], params["final_norm"])
-        return self.lm_logits(params, x_last), cache
+        return self._head(params, x[:, -1:]), cache
 
     def prefill_chunk_into_slot(self, params: Params, batch: Dict,
                                 cache: Cache, slot: jnp.ndarray,
@@ -566,7 +581,8 @@ class Model:
         assert "block_table" in cache, "prefill_chunk_into_slot needs paged"
         tokens = batch["tokens"]
         assert tokens.shape[0] == 1, "chunk prefill takes one session"
-        x = self.embed_tokens(params, tokens)
+        with scope("embed"):
+            x = self.embed_tokens(params, tokens)
         C = x.shape[1]
         start_pos = jnp.asarray(start_pos, jnp.int32)
         positions = (start_pos + jnp.arange(C))[None, :]
@@ -578,27 +594,24 @@ class Model:
 
         def body(h, inp):
             bp, pools = inp[0], inp[1:]
+            with scope("qkv"):
+                hn = apply_norm(h, bp["norm1"])
             res = attn.attention_prefill_paged(
-                bp["attn"], apply_norm(h, bp["norm1"]), pools[0], pools[1],
+                bp["attn"], hn, pools[0], pools[1],
                 slot_pages, start_pos, angles, cfg, apply_rope,
                 k_scale_pool=pools[2] if quantized_kv else None,
                 v_scale_pool=pools[3] if quantized_kv else None)
             a_out, pools = res[0], res[1:]
-            h = h + a_out
-            hn = apply_norm(h, bp["norm2"])
-            if cfg.family == "moe":
-                m_out, _ = moe.moe_forward(bp["moe"], hn, cfg)
-            else:
-                m_out = mlp_forward(bp["mlp"], hn, cfg.mlp_gated)
-            return h + m_out, pools
+            with scope("attn_out"):
+                h = h + a_out
+            return self._mlp_block(bp, h)[0], pools
 
         x, pools = jax.lax.scan(
             body, x,
             (params["blocks"],) + tuple(cache[key] for key in slab_keys))
         cache = dict(cache, pos=cache["pos"].at[slot].set(start_pos + C),
                      **dict(zip(slab_keys, pools)))
-        x_last = apply_norm(x[:, -1:], params["final_norm"])
-        return self.lm_logits(params, x_last), cache
+        return self._head(params, x[:, -1:]), cache
 
     # ------------------------------------------------------------------
     # decode
@@ -606,44 +619,31 @@ class Model:
     def _attn_block_decode(self, bp, x, k_cache, v_cache, write_pos, mask,
                            angles, backend=None, k_scale=None, v_scale=None,
                            active=None):
-        cfg = self.cfg
+        with scope("qkv"):
+            h = apply_norm(x, bp["norm1"])
         res = attn.attention_decode(
-            bp["attn"], apply_norm(x, bp["norm1"]), k_cache, v_cache,
-            write_pos, mask, angles, cfg, apply_rope,
+            bp["attn"], h, k_cache, v_cache,
+            write_pos, mask, angles, self.cfg, apply_rope,
             backend=backend or self.decode_backend,
             k_scale=k_scale, v_scale=v_scale, active=active)
-        if k_scale is not None:
-            a_out, k_cache, v_cache, k_scale, v_scale = res
-        else:
-            a_out, k_cache, v_cache = res
-        x = x + a_out
-        h = apply_norm(x, bp["norm2"])
-        if cfg.family == "moe":
-            m_out, _ = moe.moe_forward(bp["moe"], h, cfg)
-        else:
-            m_out = mlp_forward(bp["mlp"], h, cfg.mlp_gated)
-        if k_scale is not None:
-            return x + m_out, k_cache, v_cache, k_scale, v_scale
-        return x + m_out, k_cache, v_cache
+        with scope("attn_out"):
+            x = x + res[0]
+        return (self._mlp_block(bp, x)[0],) + res[1:]
 
     def _attn_block_decode_paged(self, bp, x, k_pool, v_pool, block_table,
                                  pos, mask, angles, backend=None,
                                  active=None, k_scale_pool=None,
                                  v_scale_pool=None):
-        cfg = self.cfg
+        with scope("qkv"):
+            h = apply_norm(x, bp["norm1"])
         res = attn.attention_decode_paged(
-            bp["attn"], apply_norm(x, bp["norm1"]), k_pool, v_pool,
-            block_table, pos, mask, angles, cfg, apply_rope,
+            bp["attn"], h, k_pool, v_pool,
+            block_table, pos, mask, angles, self.cfg, apply_rope,
             backend=backend or self.decode_backend, active=active,
             k_scale_pool=k_scale_pool, v_scale_pool=v_scale_pool)
-        a_out, pools = res[0], res[1:]
-        x = x + a_out
-        h = apply_norm(x, bp["norm2"])
-        if cfg.family == "moe":
-            m_out, _ = moe.moe_forward(bp["moe"], h, cfg)
-        else:
-            m_out = mlp_forward(bp["mlp"], h, cfg.mlp_gated)
-        return (x + m_out,) + pools
+        with scope("attn_out"):
+            x = x + res[0]
+        return (self._mlp_block(bp, x)[0],) + res[1:]
 
     def _mamba_block_decode(self, bp, x, h, conv):
         y, h, conv = mamba2.mamba_decode_step(
@@ -676,7 +676,8 @@ class Model:
         their cache — their (garbage) logits still come out and are
         discarded by the sampler clamp."""
         cfg = self.cfg
-        x = self.embed_tokens(params, tokens)
+        with scope("embed"):
+            x = self.embed_tokens(params, tokens)
         B = x.shape[0]
         pos = cache["pos"]
         slotted = pos.ndim == 1
@@ -778,8 +779,7 @@ class Model:
                              k=jnp.stack(k_out, axis=0), v=jnp.stack(v_out, axis=0))
         new_cache["pos"] = (pos + 1 if active is None
                             else pos + active.astype(jnp.int32))
-        x = apply_norm(x, params["final_norm"])
-        return self.lm_logits(params, x), new_cache
+        return self._head(params, x), new_cache
 
     # staticcheck: hotpath
     def decode_steps(self, params: Params, cache: Cache, tokens: jnp.ndarray,
@@ -826,13 +826,14 @@ class Model:
             active = (alive & (step < steps_left)) if masked else None
             logits, cache = self.decode_step(params, cache, tok,
                                              active=active)
-            k = jax.random.fold_in(key, step)
-            nxt = sample(logits[:, -1], k, temperature=temperature,
-                         top_k=top_k)
-            if masked:
-                nxt = jnp.where(active, nxt, tok[:, 0])
-                if eos_id is not None:
-                    alive = alive & ~(active & (nxt == eos_id))
+            with scope("sample"):
+                k = jax.random.fold_in(key, step)
+                nxt = sample(logits[:, -1], k, temperature=temperature,
+                             top_k=top_k)
+                if masked:
+                    nxt = jnp.where(active, nxt, tok[:, 0])
+                    if eos_id is not None:
+                        alive = alive & ~(active & (nxt == eos_id))
             return (cache, nxt[:, None], alive), nxt
 
         alive0 = jnp.ones((tokens.shape[0],), bool)
@@ -861,7 +862,8 @@ class Model:
 
         def embed_stage(state):
             tokens = state["tokens"]
-            x = self.embed_tokens(params, tokens)
+            with scope("embed"):
+                x = self.embed_tokens(params, tokens)
             B = x.shape[0]
             pos = state["cache"]["pos"]
             positions = (pos[:, None] if pos.ndim == 1
@@ -900,10 +902,9 @@ class Model:
             return stage
 
         def head_stage(state):
-            x = apply_norm(state["x"], params["final_norm"])
             c = dict(state["cache"])
             c["pos"] = c["pos"] + 1
-            return dict(state, logits=self.lm_logits(params, x), cache=c)
+            return dict(state, logits=self._head(params, state["x"]), cache=c)
 
         stages = [embed_stage] + [make_block_stage(i) for i in range(cfg.n_layers)] \
             + [head_stage]

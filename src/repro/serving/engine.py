@@ -17,13 +17,14 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.core.dispatch import StepProgram
+from repro.core.spans import Phases
 from repro.models.model import Model
 from repro.quant import quantize_tree
 from repro.serving.programs import jit_cache_size
@@ -45,6 +46,8 @@ class DecodeEngine:
         self.params = quantize_tree(params, quant_path) if quant_path != "bf16" else params
         self.quant_path = quant_path
         self.kv_dtype = kv_dtype
+        # host time of the streamed loop: repro.dispatch / repro.sync
+        self.phases = Phases()
         donate = (1,) if donate_cache else ()
         self._prefill = jax.jit(model.prefill)
         self._step = jax.jit(model.decode_step, donate_argnums=donate)
@@ -81,31 +84,56 @@ class DecodeEngine:
     def generate_streamed(self, batch: Dict, *, max_len: int, n_new: int,
                           temperature: float = 0.0, top_k: int = 0,
                           seed: int = 0, timed: bool = False) -> GenerationResult:
-        """One host dispatch per token (the paper's streaming workload).
+        """One host dispatch per token (the paper's streaming workload):
+        prefill, then ``decode_streamed`` from the filled cache.
 
         The generation wall is always timed (``tokens_per_s`` is real
         whether or not per-step instrumentation is on); ``timed=True``
-        additionally records per-step walls for percentile reporting."""
+        additionally records each token's gap for percentile reporting."""
         logits, cache = self.prefill(batch, max_len)
         key = jax.random.PRNGKey(seed)
         out, times = [], []
         tok = sample(logits[:, -1], key, temperature=temperature, top_k=top_k)
         out.append(tok)
-        t_gen = time.perf_counter()
-        for i in range(n_new - 1):
-            key = jax.random.fold_in(key, i)
-            t0 = time.perf_counter()
-            logits, cache = self._step(self.params, cache, self._token_shape(tok))
-            tok = sample(logits[:, -1], key, temperature=temperature, top_k=top_k)
-            jax.block_until_ready(tok)
+        t_gen = t0 = time.perf_counter()
+        # staticcheck: disable=prng-discipline -- decode_streamed folds key with the step index before each draw (the loop it replaced did the same), so its draws are disjoint from tok0's
+        for tok, cache in self.decode_streamed(
+                cache, tok, key, n_new - 1, temperature=temperature,
+                top_k=top_k):
             if timed:
-                times.append(time.perf_counter() - t0)
+                t1 = time.perf_counter()
+                times.append(t1 - t0)
+                t0 = t1
             out.append(tok)
         jax.block_until_ready(tok)
         wall = time.perf_counter() - t_gen
         tokens = jnp.stack(out, axis=1)
         tps = (n_new - 1) / wall if n_new > 1 and wall > 0 else float("nan")
         return GenerationResult(tokens, times, tps)
+
+    def decode_streamed(self, cache, tok: jnp.ndarray, key: jnp.ndarray,
+                        n_steps: int, *, temperature: float = 0.0,
+                        top_k: int = 0
+                        ) -> Iterator[Tuple[jnp.ndarray, Dict]]:
+        """Decode up to ``n_steps`` tokens from a filled ``cache`` whose
+        last sampled token is ``tok`` (B,), one host dispatch per token.
+
+        Per token: fold ``key`` with the step index, run the compiled
+        step, sample, wait for the token on the device (spanned
+        ``repro.dispatch`` and ``repro.sync``).  Yields ``(token, cache)``
+        after each step; the token stays on the device.  The step
+        donates the cache it is given, so a caller that stops early
+        continues from the last cache yielded."""
+        for i in range(n_steps):
+            with self.phases("dispatch"):
+                key = jax.random.fold_in(key, i)
+                logits, cache = self._step(self.params, cache,
+                                           self._token_shape(tok))
+                tok = sample(logits[:, -1], key, temperature=temperature,
+                             top_k=top_k)
+            with self.phases("sync"):
+                jax.block_until_ready(tok)
+            yield tok, cache
 
     def generate_fused(self, batch: Dict, *, max_len: int, n_new: int,
                        seed: int = 0, temperature: float = 0.0,

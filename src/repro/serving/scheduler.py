@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dispatch import MODES, launch_count
+from repro.core.spans import Phases
 from repro.models.model import Model
 from repro.serving.faults import FaultInjector, InjectedFault
 from repro.serving.memory import (BlockAllocator, PageStore, PrefixCache,
@@ -100,8 +101,8 @@ class SlotScheduler(VirtualClockMixin):
         self.steps_per_tick = steps_per_tick
         self.eos_id = eos_id
         self.timed = timed
-        self.host_dispatch_s = 0.0
-        self.host_sync_s = 0.0
+        # host time per phase of a tick, each phase a repro.* trace span
+        self.phases = Phases()
         self.adaptive_k = adaptive_k
         self.min_steps_per_tick = min_steps_per_tick
         self.priority_preemption = priority_preemption
@@ -228,6 +229,16 @@ class SlotScheduler(VirtualClockMixin):
         self._step_cache_base = self._progs.raw_step_cache_size() or 0
 
     # ------------------------------------------------------------- intro
+    @property
+    def host_dispatch_s(self) -> float:
+        """Host seconds building and dispatching decode work."""
+        return self.phases.totals["dispatch"]
+
+    @property
+    def host_sync_s(self) -> float:
+        """Host seconds blocked on the per-tick token transfer."""
+        return self.phases.totals["sync"]
+
     @property
     def free_slots(self) -> List[int]:
         return [i for i, s in enumerate(self.slots) if s is None]
@@ -579,43 +590,44 @@ class SlotScheduler(VirtualClockMixin):
     def _prefill_next_chunk(self, slot: int, sess: _Session) -> bool:
         """Run ONE prefill chunk (allocating its pages first); False
         when pages stay short after preemption — retried next tick."""
-        start = sess.prefilled
-        C = self._next_chunk_len(sess)
-        need = self._pages_for(start + C) - len(sess.pages)
-        if need > 0:
-            got = self._alloc_or_preempt(need, sess)
-            if got is None:
-                return False
-            base = len(sess.pages)
-            sess.pages.extend(got)
-            self.store.map_pages(slot, base, got)
-        self._sync_device()
-        chunk = jnp.asarray(sess.prefill_seq[start:start + C])[None, :]
-        logits, self.cache = self._progs.prefill_chunk(
-            self.params, {"tokens": chunk}, self.cache, jnp.int32(slot),
-            jnp.int32(start))
-        sess.prefilled = start + C
-        sess.pos = sess.prefilled
-        self.store.set_pos(slot, sess.prefilled)
-        self.prefill_tokens += C
-        self._charge(1)          # one prefill program: launch + a quantum
-        self._register_prefix(sess)
-        if sess.decoding:
-            # prefill complete: sample the first token — unless resuming,
-            # where the last generated token re-feeds through decode
-            if sess.resume and sess.tokens:
-                sess.resume = False
-            else:
-                sess.resume = False
-                salt = 2 * self._admit_count
-                self._admit_count += 1
-                tok = int(self._sample(logits[:, -1], salt)[0])
-                sess.tokens.append(tok)
-                self._stamp(sess)
-                self.events.append(("token", sess.sid, slot, tok))
-                if sess.done or self._hit_eos(tok):
-                    self._finish(slot, sess)
-        return True
+        with self.phases("prefill"):
+            start = sess.prefilled
+            C = self._next_chunk_len(sess)
+            need = self._pages_for(start + C) - len(sess.pages)
+            if need > 0:
+                got = self._alloc_or_preempt(need, sess)
+                if got is None:
+                    return False
+                base = len(sess.pages)
+                sess.pages.extend(got)
+                self.store.map_pages(slot, base, got)
+            self._sync_device()
+            chunk = jnp.asarray(sess.prefill_seq[start:start + C])[None, :]
+            logits, self.cache = self._progs.prefill_chunk(
+                self.params, {"tokens": chunk}, self.cache, jnp.int32(slot),
+                jnp.int32(start))
+            sess.prefilled = start + C
+            sess.pos = sess.prefilled
+            self.store.set_pos(slot, sess.prefilled)
+            self.prefill_tokens += C
+            self._charge(1)          # one prefill program: launch + a quantum
+            self._register_prefix(sess)
+            if sess.decoding:
+                # prefill complete: sample the first token — unless resuming,
+                # where the last generated token re-feeds through decode
+                if sess.resume and sess.tokens:
+                    sess.resume = False
+                else:
+                    sess.resume = False
+                    salt = 2 * self._admit_count
+                    self._admit_count += 1
+                    tok = int(self._sample(logits[:, -1], salt)[0])
+                    sess.tokens.append(tok)
+                    self._stamp(sess)
+                    self.events.append(("token", sess.sid, slot, tok))
+                    if sess.done or self._hit_eos(tok):
+                        self._finish(slot, sess)
+            return True
 
     def _register_prefix(self, sess: _Session) -> None:
         """Index the session's fully-prefilled pages for sharing."""
@@ -841,9 +853,10 @@ class SlotScheduler(VirtualClockMixin):
             while self.slots[slot] is None and self.waiting:
                 sess = self.waiting.popleft()
                 prompt = jnp.asarray(sess.request.prompt)[None, :]
-                logits, self.cache = self._progs.prefill_slot(
-                    self.params, {"tokens": prompt}, self.cache,
-                    jnp.int32(slot))
+                with self.phases("prefill"):
+                    logits, self.cache = self._progs.prefill_slot(
+                        self.params, {"tokens": prompt}, self.cache,
+                        jnp.int32(slot))
                 sess.slot = slot
                 sess.admitted_tick = self.tick_count
                 self.slots[slot] = sess
@@ -936,46 +949,52 @@ class SlotScheduler(VirtualClockMixin):
 
     def tick(self) -> List[Event]:
         """One iteration: continue chunked prefills, backfill, tier idle
-        work, one batched decode dispatch, evict completed sessions."""
-        n_before = len(self.events)
-        steps0, pf0 = self.decode_steps, self.prefill_tokens
-        self._release_arrivals()
-        self._poll_faults()
-        if self.paged:
-            for slot, sess in enumerate(self.slots):
-                if sess is not None and not sess.decoding:
-                    self._prefill_next_chunk(slot, sess)
-        self._backfill()
-        if self.tiered and not self.waiting and not self._arrivals:
-            # no admission pressure: let the policy pre-migrate
-            # (LookAheadSpill shadow-copies the predicted victim)
-            self.store.policy.idle_tick(self)
-        if self.paged and self.self_audit and not self.waiting \
-                and all(s is None for s in self.slots):
-            self._run_audit()     # idle tick: audit the page accounting
-        if self.steps_per_tick == 1:
-            self._decode_tick_single()
-        else:
-            self._decode_tick_horizon(self._tick_horizon())
-        if self._pressure_holds and self.decode_steps == steps0 \
-                and self.prefill_tokens == pf0 \
-                and len(self.events) == n_before:
-            # a pressure spike can gate every admission with nothing
-            # resident and no arrival to fast-forward to: jump the clock
-            # to the next hold expiry so the spike passes
-            self.now_s = max(self.now_s,
-                             min(e for e, _ in self._pressure_holds))
-        self.tick_count += 1
-        return self.events[n_before:]
+        work, one batched decode dispatch, evict completed sessions.
+        Spanned ``repro.tick``, with its phases (``spans.PHASES``)
+        inside."""
+        with self.phases("tick"):
+            n_before = len(self.events)
+            steps0, pf0 = self.decode_steps, self.prefill_tokens
+            with self.phases("admit"):
+                self._release_arrivals()
+                self._poll_faults()
+            if self.paged:
+                for slot, sess in enumerate(self.slots):
+                    if sess is not None and not sess.decoding:
+                        self._prefill_next_chunk(slot, sess)
+            with self.phases("admit"):
+                self._backfill()
+            if self.tiered and not self.waiting and not self._arrivals:
+                # no admission pressure: let the policy pre-migrate
+                # (LookAheadSpill shadow-copies the predicted victim)
+                self.store.policy.idle_tick(self)
+            if self.paged and self.self_audit and not self.waiting \
+                    and all(s is None for s in self.slots):
+                self._run_audit()     # idle tick: audit the page accounting
+            if self.steps_per_tick == 1:
+                self._decode_tick_single()
+            else:
+                self._decode_tick_horizon(self._tick_horizon())
+            if self._pressure_holds and self.decode_steps == steps0 \
+                    and self.prefill_tokens == pf0 \
+                    and len(self.events) == n_before:
+                # a pressure spike can gate every admission with nothing
+                # resident and no arrival to fast-forward to: jump the clock
+                # to the next hold expiry so the spike passes
+                self.now_s = max(self.now_s,
+                                 min(e for e, _ in self._pressure_holds))
+            self.tick_count += 1
+            return self.events[n_before:]
 
     def _decode_tick_single(self) -> None:  # staticcheck: hotpath
         """K=1 decode: one dispatch + one host round-trip per token."""
         if self.paged:
-            for slot, sess in list(enumerate(self.slots)):
-                if sess is not None and sess.decoding and \
-                        self.slots[slot] is sess:
-                    self._ensure_decode_page(slot, sess)
-            self._sync_device()
+            with self.phases("pages"):
+                for slot, sess in list(enumerate(self.slots)):
+                    if sess is not None and sess.decoding and \
+                            self.slots[slot] is sess:
+                        self._ensure_decode_page(slot, sess)
+                self._sync_device()
         active = [(i, s) for i, s in enumerate(self.slots)
                   if s is not None and (not self.paged or s.decoding)]
         if not active:
@@ -989,54 +1008,49 @@ class SlotScheduler(VirtualClockMixin):
             self.step_kv_blocks.append(sum(
                 -(-(sess.pos + 1) // self.page_size)
                 for _, sess in active))
-        t0 = time.perf_counter()
-        logits, self.cache = self._run_step(jnp.asarray(toks))
-        nxt = self._sample(logits[:, -1], 2 * self.tick_count + 1)
-        t1 = time.perf_counter()
-        # staticcheck: disable=hot-sync -- the ONE deliberate per-tick sync: sampled tokens must reach the host to be emitted
-        nxt = np.asarray(nxt)
-        t2 = time.perf_counter()
-        self.host_dispatch_s += t1 - t0
-        self.host_sync_s += t2 - t1
-        dt = t2 - t0
-        self.decode_steps += 1
-        self._charge(1)
-        screened: set = set()
-        if self._screen_logits:
-            # NaN/Inf screen on this step's logits — a writable HOST
-            # copy: injected poison lands here, device state stays clean
-            # staticcheck: disable=hot-sync -- NaN screen needs a writable host copy; only taken when --screen-logits is on (chaos runs)
-            last = np.array(logits[:, -1], np.float32)
+        with self.phases("dispatch") as dispatch:
+            logits, self.cache = self._run_step(jnp.asarray(toks))
+            nxt = self._sample(logits[:, -1], 2 * self.tick_count + 1)
+        with self.phases("sync") as sync:
+            # staticcheck: disable=hot-sync -- the ONE deliberate per-tick sync: sampled tokens must reach the host to be emitted
+            nxt = np.asarray(nxt)
+        dt = dispatch.seconds + sync.seconds
+        with self.phases("emit"):
+            self.decode_steps += 1
+            self._charge(1)
+            screened: set = set()
+            if self._screen_logits:
+                # NaN/Inf screen on this step's logits — a writable
+                # HOST copy: injected poison lands here, device state
+                # stays clean
+                # staticcheck: disable=hot-sync -- NaN screen needs a writable host copy; only taken when --screen-logits is on (chaos runs)
+                last = np.array(logits[:, -1], np.float32)
+                for slot, sess in active:
+                    if self._poison and self._take_poison(sess.sid):
+                        last[slot] = np.nan
+                    if not np.isfinite(last[slot]).all():
+                        screened.add(slot)
             for slot, sess in active:
-                if self._poison and self._take_poison(sess.sid):
-                    last[slot] = np.nan
-                if not np.isfinite(last[slot]).all():
-                    screened.add(slot)
-        for slot, sess in active:
-            if slot in screened:
-                # poisoned step never commits: quarantine the lane,
-                # other lanes proceed untouched
-                self._quarantine(slot, sess)
-                continue
-            sess.pos += 1
-            if self.paged:
-                self.store.mirror_pos(slot, sess.pos)
-            tok = int(nxt[slot])
-            sess.tokens.append(tok)
-            self._stamp(sess)
-            if self.timed:
-                sess.step_times_s.append(dt)
-            self.events.append(("token", sess.sid, slot, tok))
-            if sess.done or self._hit_eos(tok):
-                self._finish(slot, sess)
+                if slot in screened:
+                    # poisoned step never commits: quarantine the lane,
+                    # other lanes proceed untouched
+                    self._quarantine(slot, sess)
+                    continue
+                sess.pos += 1
+                if self.paged:
+                    self.store.mirror_pos(slot, sess.pos)
+                tok = int(nxt[slot])
+                sess.tokens.append(tok)
+                self._stamp(sess)
+                if self.timed:
+                    sess.step_times_s.append(dt)
+                self.events.append(("token", sess.sid, slot, tok))
+                if sess.done or self._hit_eos(tok):
+                    self._finish(slot, sess)
 
-    def _decode_tick_horizon(self, K: int) -> None:  # staticcheck: hotpath
-        """Horizon-K fused decode: ONE program advances every live slot
-        up to ``K`` tokens (lax.scan, on-device sampling), the
-        (n_slots, K) token matrix returns in one transfer, and the host
-        reconciles afterwards — trimming lanes that hit EOS or budget
-        mid-horizon (masked no-ops on device).  Pages covering each
-        granted horizon are reserved BEFORE dispatch."""
+    def _plan_horizon(self, K: int) -> Dict[int, int]:
+        """Steps granted to each decoding slot this macro-tick (paged:
+        pages reserved for them)."""
         plan: Dict[int, int] = {}
         for slot, sess in list(enumerate(self.slots)):
             # skip free lanes, mid-prefill lanes, and lanes an earlier
@@ -1048,8 +1062,21 @@ class SlotScheduler(VirtualClockMixin):
             assert want >= 1, "finished session left in a slot"
             plan[slot] = (self._reserve_horizon(slot, sess, want)
                           if self.paged else want)
+        return plan
+
+    def _decode_tick_horizon(self, K: int) -> None:  # staticcheck: hotpath
+        """Horizon-K fused decode: ONE program advances every live slot
+        up to ``K`` tokens (lax.scan, on-device sampling), the
+        (n_slots, K) token matrix returns in one transfer, and the host
+        reconciles afterwards — trimming lanes that hit EOS or budget
+        mid-horizon (masked no-ops on device).  Pages covering each
+        granted horizon are reserved BEFORE dispatch."""
         if self.paged:
-            self._sync_device(pos_always=False)
+            with self.phases("pages"):
+                plan = self._plan_horizon(K)
+                self._sync_device(pos_always=False)
+        else:
+            plan = self._plan_horizon(K)
         active = [(i, s) for i, s in enumerate(self.slots)
                   if plan.get(i, 0) >= 1 and s is not None]
         if not active:
@@ -1060,71 +1087,69 @@ class SlotScheduler(VirtualClockMixin):
             toks[slot, 0] = sess.next_input_token
             steps_left[slot] = plan[slot]
         key = jax.random.fold_in(self.key, 2 * self.tick_count + 1)
-        t0 = time.perf_counter()
-        tok_mat, self.cache = self._progs.steps(
-            self.params, self.cache, jnp.asarray(toks), key,
-            jnp.asarray(steps_left), horizon=K,
-            temperature=self.temperature, top_k=self.top_k,
-            eos_id=self.eos_id)
-        t1 = time.perf_counter()
-        # staticcheck: disable=hot-sync -- the ONE deliberate macro-tick sync: up to K*slots sampled tokens in one transfer
-        tok_mat = np.asarray(tok_mat)
-        t2 = time.perf_counter()
-        screen = self._screen_logits
-        if screen:
-            tok_mat = np.array(tok_mat)     # writable host copy
-            for slot, sess in active:
-                if self._poison and self._take_poison(sess.sid):
-                    # out-of-vocab sentinel on the lane's whole horizon:
-                    # the range check below quarantines at step 0, so no
-                    # poisoned token ever commits
-                    tok_mat[slot, :] = self._vocab
-        self.host_dispatch_s += t1 - t0
-        self.host_sync_s += t2 - t1
-        dt = t2 - t0
-        self.decode_steps += 1
-        self.horizon_hist[K] += 1
-        # ---- reconciliation: step-major walk mirrors the device scan ----
-        per_tok_dt = dt / K
-        max_steps = max(plan[slot] for slot, _ in active)
-        vt0 = self.now_s + self.virtual_dispatch_s
-        self._charge(max_steps)
-        kv_blocks = [0] * max_steps
-        emitted = [0] * max_steps
-        done: set = set()
-        for j in range(max_steps):
-            for slot, sess in active:
-                if slot in done or j >= plan[slot]:
-                    continue
-                tok = int(tok_mat[slot, j])
-                if screen and not 0 <= tok < self._vocab:
-                    # screened lane: nothing from this horizon commits
-                    done.add(slot)
-                    self._quarantine(slot, sess)
-                    continue
-                sess.pos += 1
-                if self.paged:
-                    self.store.mirror_pos(slot, sess.pos)
-                    # blocks this device step walked for the lane: its
-                    # live length after the write (same accounting as K=1)
-                    kv_blocks[j] += -(-sess.pos // self.page_size)
-                emitted[j] += 1
-                sess.tokens.append(tok)
-                # step j's token leaves at the j+1'th quantum — stamps
-                # see positions inside the fused horizon, not tick ends
-                self._stamp(sess, vt0 + (j + 1) * self.virtual_step_s)
-                if self.timed:
-                    sess.step_times_s.append(per_tok_dt)
-                self.events.append(("token", sess.sid, slot, tok))
-                if sess.done or self._hit_eos(tok):
-                    # remaining device steps were masked no-ops
-                    done.add(slot)
-                    self._finish(slot, sess)
-        if self.paged:
-            # count only device steps that had >= 1 live lane (trailing
-            # all-masked steps move no live pages)
-            self.step_kv_blocks.extend(
-                b for b, n in zip(kv_blocks, emitted) if n)
+        with self.phases("dispatch") as dispatch:
+            tok_mat, self.cache = self._progs.steps(
+                self.params, self.cache, jnp.asarray(toks), key,
+                jnp.asarray(steps_left), horizon=K,
+                temperature=self.temperature, top_k=self.top_k,
+                eos_id=self.eos_id)
+        with self.phases("sync") as sync:
+            # staticcheck: disable=hot-sync -- the ONE deliberate macro-tick sync: up to K*slots sampled tokens in one transfer
+            tok_mat = np.asarray(tok_mat)
+        dt = dispatch.seconds + sync.seconds
+        with self.phases("emit"):
+            screen = self._screen_logits
+            if screen:
+                tok_mat = np.array(tok_mat)     # writable host copy
+                for slot, sess in active:
+                    if self._poison and self._take_poison(sess.sid):
+                        # out-of-vocab sentinel on the lane's whole
+                        # horizon: the range check below quarantines at
+                        # step 0, so no poisoned token ever commits
+                        tok_mat[slot, :] = self._vocab
+            self.decode_steps += 1
+            self.horizon_hist[K] += 1
+            per_tok_dt = dt / K
+            max_steps = max(plan[slot] for slot, _ in active)
+            vt0 = self.now_s + self.virtual_dispatch_s
+            self._charge(max_steps)
+            kv_blocks = [0] * max_steps
+            emitted = [0] * max_steps
+            done: set = set()
+            for j in range(max_steps):
+                for slot, sess in active:
+                    if slot in done or j >= plan[slot]:
+                        continue
+                    tok = int(tok_mat[slot, j])
+                    if screen and not 0 <= tok < self._vocab:
+                        # screened lane: nothing from this horizon commits
+                        done.add(slot)
+                        self._quarantine(slot, sess)
+                        continue
+                    sess.pos += 1
+                    if self.paged:
+                        self.store.mirror_pos(slot, sess.pos)
+                        # blocks this device step walked for the lane:
+                        # its live length after the write (same
+                        # accounting as K=1)
+                        kv_blocks[j] += -(-sess.pos // self.page_size)
+                    emitted[j] += 1
+                    sess.tokens.append(tok)
+                    # step j's token leaves at the j+1'th quantum — stamps
+                    # see positions inside the fused horizon, not tick ends
+                    self._stamp(sess, vt0 + (j + 1) * self.virtual_step_s)
+                    if self.timed:
+                        sess.step_times_s.append(per_tok_dt)
+                    self.events.append(("token", sess.sid, slot, tok))
+                    if sess.done or self._hit_eos(tok):
+                        # remaining device steps were masked no-ops
+                        done.add(slot)
+                        self._finish(slot, sess)
+            if self.paged:
+                # count only device steps that had >= 1 live lane
+                # (trailing all-masked steps move no live pages)
+                self.step_kv_blocks.extend(
+                    b for b, n in zip(kv_blocks, emitted) if n)
 
     def run(self) -> ContinuousResult:
         """Drive until the queue and slots drain.  Callable repeatedly
@@ -1136,7 +1161,7 @@ class SlotScheduler(VirtualClockMixin):
         disp0 = self.decode_steps
         arr0 = self.arrivals_released
         hist0 = collections.Counter(self.horizon_hist)
-        hd0, hs0 = self.host_dispatch_s, self.host_sync_s
+        phase0 = self.phases.snapshot()
         blk0 = len(self.step_kv_blocks) if self.paged else 0
         pf0, ph0 = self.prefill_tokens, self.prefix_hits
         ps0, cw0 = self.prefix_tokens_saved, self.cow_copies
@@ -1204,8 +1229,10 @@ class SlotScheduler(VirtualClockMixin):
             steps_per_tick=self.steps_per_tick,
             dispatches=self.decode_steps - disp0,
             run_tokens=n_tokens,
-            host_dispatch_s=self.host_dispatch_s - hd0,
-            host_sync_s=self.host_sync_s - hs0,
+            host_dispatch_s=self.host_dispatch_s - phase0["dispatch"],
+            host_sync_s=self.host_sync_s - phase0["sync"],
+            host_phase_s={k: v - phase0[k]
+                          for k, v in self.phases.totals.items()},
             prefill_tokens=self.prefill_tokens - pf0,
             prefix_hits=self.prefix_hits - ph0,
             prefix_tokens_saved=self.prefix_tokens_saved - ps0,

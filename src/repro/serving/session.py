@@ -88,7 +88,8 @@ class ContinuousResult:
     **This ``run()`` call only** (delta since the call started):
     ``ticks``, ``wall_s``, ``tokens_per_s``, ``preemptions``,
     ``dispatches``, ``run_tokens``, ``step_kv_blocks``,
-    ``host_dispatch_s``, ``host_sync_s``, ``prefill_tokens``,
+    ``host_dispatch_s``, ``host_sync_s``, ``host_phase_s``,
+    ``prefill_tokens``,
     ``prefix_hits``, ``prefix_tokens_saved``, ``cow_copies``,
     ``arrivals``, ``horizon_hist``, the tier counters
     ``pages_spilled`` / ``pages_restored`` / ``tier_restores`` /
@@ -122,6 +123,10 @@ class ContinuousResult:
                                      # horizon amortises)
     host_sync_s: float = 0.0         # host wall blocked on the per-tick
                                      # token transfer
+    host_phase_s: Dict[str, float] = dataclasses.field(
+        default_factory=dict)        # host seconds per tick phase
+                                     # (core/spans.py PHASES), each
+                                     # phase's own time
     prefill_tokens: int = 0          # tokens actually dispatched through
                                      # prefill programs this run()
     prefix_hits: int = 0             # admissions that matched a cached

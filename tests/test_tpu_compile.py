@@ -14,7 +14,10 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU compiler's library, and it
 keeps it until it exits.
 """
+import contextlib
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.models import attention as attention_mod
+from repro.models import model as model_mod
 from repro.kernels.int4_matmul.int4_matmul import int4_matmul_pallas
 from repro.kernels.int4_matmul.ops import blocks
 from repro.kernels.paged_decode_attention.paged_decode_attention import (
@@ -105,3 +110,106 @@ def test_rmsnorm_compiles(one_chip):
                    _spec((8, CFG.d_model), jnp.bfloat16, one_chip),
                    _spec((CFG.d_model,), jnp.float32, one_chip))
     assert "tpu_custom_call" in hlo
+
+
+def _program(text: str) -> str:
+    """Compiled HLO without what only names it: op metadata and the
+    stack-frame tables that metadata points into."""
+    lines = text.splitlines()
+    first = next(i for i, ln in enumerate(lines)
+                 if ln.startswith(("%", "ENTRY")))
+    return re.sub(r",? metadata=\{[^}]*\}", "",
+                  "\n".join([lines[0]] + lines[first:]))
+
+
+def _decode_step_hlo(one_chip, backend, paged, n_layers):
+    """The decode step of the serving cells (batch 1 contiguous, or 4
+    slots paged) at full width and ``n_layers``, compiled for the chip."""
+    model = model_mod.Model(dataclasses.replace(CFG, n_layers=n_layers),
+                            decode_backend=backend)
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+    B = SLOTS if paged else 1
+    cache = place(jax.eval_shape(lambda: model.init_cache(
+        B, MAX_BLOCKS * PAGE, paged=paged, page_size=PAGE)))
+    args = (place(model.abstract_params()), cache,
+            _spec((B, 1), jnp.int32, one_chip))
+    return jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        *args).compile().as_text()
+
+
+STEPS = pytest.mark.parametrize("backend,paged", [("sdpa", False),
+                                                  ("pallas", True)],
+                                ids=["contiguous", "paged-fused"])
+
+
+@STEPS
+def test_scopes_change_only_metadata(one_chip, monkeypatch, backend, paged):
+    """The device scopes (core/spans.py) name ops and build nothing: the
+    decode step compiled for the chip with and without them is one
+    program.  Two layers at full width; the paged step's HLO numbers a
+    few instructions differently, so there names are compared without
+    their numbers."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def compiled():
+        return _decode_step_hlo(one_chip, backend, paged, 2)
+
+    scoped = compiled()
+    assert "/mlp/" in scoped and "/kv_write/" in scoped
+    for mod in (model_mod, attention_mod):
+        monkeypatch.setattr(mod, "scope",
+                            lambda name: contextlib.nullcontext())
+    bare = compiled()
+    assert "/mlp/" not in bare
+    a, b = _program(scoped), _program(bare)
+    if paged:
+        a, b = (re.sub(r"\.\d+\b", ".N", t) for t in (a, b))
+    assert a == b
+
+
+_INSTR = re.compile(r"^\s+(?:ROOT )?%([\w.-]+) = (.*?) ([a-z][a-z0-9-]*)"
+                    r"\((.*)$")
+_PASS_THROUGH = ("while", "tuple", "get-tuple-element", "bitcast",
+                 "parameter")
+
+
+def _ops_reading(text: str, shape: re.Pattern):
+    """(op, op_name) of each op the chip runs as one (an instruction
+    outside the fused computations) that has an operand of ``shape``;
+    the loop and tuple plumbing that only passes arrays on is left
+    out."""
+    shapes, ops, fused, comp = {}, [], set(), None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            continue
+        m = _INSTR.match(line)
+        if m:
+            name, out_shape, opcode, rest = m.groups()
+            shapes[name] = out_shape
+            if opcode == "fusion":
+                fused |= set(re.findall(r"calls=(%[\w.-]+)", rest))
+            ops.append((comp, name, opcode, rest))
+    return [(name, re.findall(r'op_name="([^"]*)"', rest))
+            for comp, name, opcode, rest in ops
+            if comp not in fused and opcode not in _PASS_THROUGH
+            and any(shape.search(shapes.get(o, "")) for o in
+                    re.findall(r"%([\w.-]+)", rest.split("), ")[0]))]
+
+
+@STEPS
+def test_every_op_reading_an_mlp_weight_is_scoped_mlp(one_chip, monkeypatch,
+                                                      backend, paged):
+    """Every op of the decode step that reads an MLP weight
+    ([2048,11008] or [11008,2048] at Qwen2.5-3B widths) carries the
+    ``mlp`` scope, so a trace charges all MLP weight streaming to it.
+    Four layers: at two, the chip's compiler prefetches the batch-1
+    step's stacked down-projection in an unnamed copy, which it does not
+    at the cells' depth."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mlp_weight = re.compile(r"\b(2048,11008|11008,2048)\]")
+    readers = _ops_reading(_decode_step_hlo(one_chip, backend, paged, 4),
+                           mlp_weight)
+    assert readers
+    assert all(n and "/mlp/" in n[0] for _, n in readers), readers
