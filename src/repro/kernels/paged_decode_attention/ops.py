@@ -1,5 +1,11 @@
 """jit'd public wrapper + analytic traffic accounting for the fused
-paged decode-attention kernel."""
+paged decode-attention kernel.
+
+On a model-dtype pool the kernel runs one program per lane; each walks
+its live pages in compute blocks of ``pages_per_block(page,
+max_blocks)`` pages (512 tokens at page 16), double-buffered page DMAs
+from the pool.  ``walk_blocks`` bounds how many blocks a run's decode
+steps walked.  An int8 pool keeps one grid step per (lane, page)."""
 from __future__ import annotations
 
 from typing import Optional, Sequence
@@ -9,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.paged_decode_attention.paged_decode_attention import (
-    paged_decode_attention_pallas)
+    paged_decode_attention_pallas, pages_per_block)
 
 
 # staticcheck: hotpath
@@ -22,11 +28,13 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     """q (B, Hq, hd); k_pool/v_pool (n_pages, Hkv, page, hd);
     block_table (B, max_blocks); lengths (B,) -> (B, Hq, hd).
 
-    Reads each slot's allocated pages in place through the block table
-    (scalar-prefetch indirection) — no materialised virtual view.  With
-    scale pools (n_pages, Hkv, page) the pools hold int8 codes and the
-    kernel applies the scales in-register inside its page sweep.
-    Interpret mode off-TPU."""
+    Reads each slot's pages in place through the block table
+    (scalar-prefetch indirection) — no materialised virtual view.  On a
+    model-dtype pool one program per slot copies its live pages block by
+    block, and nothing past a slot's last live page.  With scale pools
+    (n_pages, Hkv, page) the pools hold int8 codes, read in one
+    (slot, page) grid step a page, and the kernel applies the scales
+    in-register inside its page sweep.  Interpret mode off-TPU."""
     interpret = jax.default_backend() != "tpu"
     return paged_decode_attention_pallas(q, k_pool, v_pool, block_table,
                                          lengths, k_scale_pool,
@@ -50,9 +58,11 @@ def traffic_bytes(live_blocks: int, page_size: int, Hkv: int, hd: int,
     """Analytic per-decode-step HBM KV traffic for the two paged routes.
 
     ``live_blocks`` is the summed ``ceil(live_len/page)`` over slots at
-    that step (what the fused kernel actually walks; skipped sentinel
-    blocks cost nothing).  The gather route is charged per layer for the
-    full virtual view three times: the gather's pool read, the
+    that step: the pages the fused kernel reads, since each slot's walk
+    stops at its last live page (on an int8 pool the page grid skips
+    the compute of later pages, and a sentinel entry re-fetches
+    nothing).  The gather route is charged per layer for the full
+    virtual view three times: the gather's pool read, the
     materialised-view write, and the SDPA's read of that view — the two
     middle terms are the traffic the fused kernel deletes.
 
@@ -79,6 +89,23 @@ def traffic_bytes(live_blocks: int, page_size: int, Hkv: int, hd: int,
         "gather_sdpa": gather,
         "floor": n_layers * live * stored,
     }
+
+
+def walk_blocks(step_kv_blocks: Sequence[int], page: int, max_blocks: int,
+                n_slots: int) -> float:
+    """At most how many compute blocks a layer of a decode step walks on
+    a model-dtype pool, as a mean over a run's steps.
+
+    ``step_kv_blocks`` is the scheduler's per-decode-step trace of live
+    pages summed over lanes (``ContinuousResult.step_kv_blocks``).  A
+    lane with ``pages_b`` live pages walks ``ceil(pages_b / ppb)``
+    blocks; the trace keeps only the sum over lanes, so the walk is
+    bounded by one partial block a lane, and by the whole table."""
+    ppb = pages_per_block(page, max_blocks)
+    pages = np.asarray(step_kv_blocks, np.int64)
+    most = np.minimum(n_slots * -(-max_blocks // ppb),
+                      (pages + n_slots * (ppb - 1)) // ppb)
+    return float(np.mean(most))
 
 
 def serving_traffic_bytes(step_kv_blocks: Sequence[int], cfg, *,

@@ -449,7 +449,9 @@ def serve_continuous(engine: DecodeEngine, cfg, args):
                   f"{res.host_pages_used} host pages resident")
         if res.step_kv_blocks:
             from repro.kernels.paged_decode_attention.ops import (
-                serving_traffic_bytes)
+                serving_traffic_bytes, walk_blocks)
+            from repro.kernels.paged_decode_attention.paged_decode_attention \
+                import PAGE_ROWS
             tb = serving_traffic_bytes(res.step_kv_blocks, cfg,
                                        page_size=args.page_size,
                                        n_slots=args.slots,
@@ -463,6 +465,12 @@ def serve_continuous(engine: DecodeEngine, cfg, args):
             print(f"per-step KV traffic ({route}): {moved / 1024:.1f} KiB "
                   f"(fused would move {tb['fused'] / 1024:.1f}, gather "
                   f"{tb['gather_sdpa'] / 1024:.1f}{quant_note})")
+            if (backend == "pallas" and args.kv_quant == "none"
+                    and args.page_size % PAGE_ROWS == 0):
+                w = walk_blocks(res.step_kv_blocks, args.page_size,
+                                max_blocks, args.slots)
+                print(f"fused walk: at most {w:.1f} compute blocks a "
+                      f"layer-step")
     if args.quant != "bf16" or args.kv_quant != "none":
         from repro.quant import tree_weight_traffic
         wb = tree_weight_traffic(engine.params)

@@ -20,11 +20,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs import get_config
 from repro.kernels.paged_decode_attention.ops import (paged_decode_attention,
                                                       serving_traffic_bytes,
-                                                      traffic_bytes)
+                                                      traffic_bytes,
+                                                      walk_blocks)
+from repro.kernels.paged_decode_attention.paged_decode_attention import (
+    paged_decode_attention_pallas, pages_per_block)
 from repro.kernels.paged_decode_attention.ref import paged_decode_attention_ref
 from repro.models import Model
 from repro.serving import DecodeEngine, SessionRequest, SlotScheduler
@@ -44,7 +48,73 @@ def _rand_pool(key, n_pages, page, Hkv, hd, dtype=jnp.float32):
     return k_pool, v_pool
 
 
+def walk_case(page, max_blocks, lengths, seed=0):
+    """A block table whose lanes hold ``lengths`` tokens on shuffled
+    pages.  Returns the table, the lengths and the pages no lane reads:
+    the garbage page 0, every page no table row holds, and the pages of
+    a lane's row past its last live page."""
+    B = len(lengths)
+    n_pages = 1 + B * max_blocks
+    rng = np.random.RandomState(seed)
+    perm = iter(rng.permutation(np.arange(1, n_pages)))
+    bt = np.zeros((B, max_blocks), np.int32)
+    live = set()
+    for b, n in enumerate(lengths):
+        for i in range(min(-(-n // page), max_blocks)):
+            bt[b, i] = next(perm)
+            live.add(int(bt[b, i]))
+        # pages past the live ones: allocated to the lane, not yet read
+        for i in range(min(-(-n // page), max_blocks), max_blocks - 1, 3):
+            bt[b, i] = next(perm)
+    dead = np.asarray(sorted(set(range(n_pages)) - live))
+    return (jnp.asarray(bt), jnp.asarray(lengths, jnp.int32), n_pages,
+            dead)
+
+
+# (page, max_blocks, lengths): at page 16 a compute block is 512 tokens;
+# lengths 0, 1, page-1, one block, one block + 1 and the whole table;
+# max_blocks not a multiple of the block's pages (70 = 2 x 32 + 6); a
+# length 100 tokens past its table, on a lane whose next lane is live,
+# attends over its own row alone.  Page 4 takes the page grid (see
+# test_pool_takes_the_walk_or_the_page_grid) under the same contract
+WALKS = [(16, 70, (0, 1, 15, 512, 513, 70 * 16)),
+         (8, 100, (0, 1, 7, 512, 513, 100 * 8)),
+         (4, 200, (0, 1, 3, 512, 513, 200 * 4)),
+         (16, 4, (0, 1, 15, 64)),                # the table is one block
+         (16, 40, (40 * 16 + 100, 513, 0, 1))]
+
+
 class TestKernelVsRef:
+    @pytest.mark.parametrize("page,max_blocks,lengths", WALKS,
+                             ids=["page16", "page8", "page4", "one-block",
+                                  "past-table"])
+    def test_block_walk_matches_ref_with_nan_behind_every_dead_page(
+            self, capsys, page, max_blocks, lengths):
+        """Each lane walks ``ceil(len / bk)`` blocks and copies only its
+        live pages: with NaN in the garbage page, in every page no lane
+        holds and in the allocated pages past each length, the output
+        still equals the reference over the clean pool, to the last
+        row of a partial block."""
+        Hq, Hkv, hd = 8, 2, 32
+        bt, ln, n_pages, dead = walk_case(page, max_blocks, lengths)
+        ppb = pages_per_block(page, max_blocks)
+        assert max(lengths) > ppb * page or max_blocks <= ppb
+        ks = jax.random.split(KEY, 3)
+        q = jax.random.normal(ks[0], (len(lengths), Hq, hd), jnp.float32)
+        k_pool, v_pool = _rand_pool(ks[1], n_pages, page, Hkv, hd)
+        ref = paged_decode_attention_ref(q, k_pool, v_pool, bt, ln)
+        # the TPU interpreter: VMEM starts as NaN, DMAs land on their
+        # wait, a read out of bounds raises, and a DMA racing a read of
+        # its buffer prints a race
+        out = paged_decode_attention_pallas(
+            q, k_pool.at[dead].set(jnp.nan), v_pool.at[dead].set(jnp.nan),
+            bt, ln, interpret=pltpu.InterpretParams(detect_races=True))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+        assert all(bool(jnp.all(out[b] == 0))      # length-0 lanes
+                   for b, n in enumerate(lengths) if n == 0)
+        assert "RACE" not in capsys.readouterr().out
+
     @pytest.mark.parametrize("page", TABLE10_PAGE_SIZES)
     def test_matches_gather_ref_all_table10_page_sizes(self, page):
         B, Hq, Hkv, hd, max_blocks = 3, 8, 2, 64, 4
@@ -131,6 +201,56 @@ class TestKernelVsRef:
         assert tb["fused"] == 3 * 6 * 8 * kv
         assert tb["gather_sdpa"] == 3 * 3 * (4 * 4 * 8) * kv
         assert tb["fused"] < tb["gather_sdpa"]
+
+    def test_walk_blocks_bounds_the_walk_of_each_step(self):
+        """From the summed live pages of a step, ``walk_blocks`` bounds
+        the compute blocks the lanes walk: one partial block a lane, and
+        never more than the whole table."""
+        page, max_blocks, lengths = WALKS[0]
+        ppb = pages_per_block(page, max_blocks)
+        steps = [lengths, (max_blocks * page,) * len(lengths)]
+        pages = [sum(-(-n // page) for n in s) for s in steps]
+        walked = [sum(-(-n // (ppb * page)) for n in s) for s in steps]
+        assert ppb == 32 and walked == [8, 18]
+        # (137 + 6 x 31) // 32 = 10, and the whole table's 6 x 3 = 18
+        assert walk_blocks(pages, page, max_blocks, len(lengths)) == 14.0
+        assert walk_blocks(pages[1:], page, max_blocks, len(lengths)) == 18
+
+    def test_int8_fused_traffic_is_the_floor(self):
+        """Under int8 the fused route reads live codes and scales once
+        at stored width; the gather route sits above that floor."""
+        kw = dict(n_slots=4, max_blocks=70, n_layers=2, kv_quant="int8")
+        tb = traffic_bytes(100, 16, 2, 128, **kw)
+        assert tb["fused"] == tb["floor"] == 2 * 100 * 16 * 2 * 2 * (128 + 4)
+        assert tb["gather_sdpa"] > tb["floor"]
+
+    @pytest.mark.parametrize("page,quantized,grid",
+                             [(16, False, (2,)), (8, False, (2,)),
+                              (4, False, (2, 3)), (12, False, (2, 3)),
+                              (16, True, (2, 3))],
+                             ids=["page16", "page8", "page4", "page12",
+                                  "int8"])
+    def test_pool_takes_the_walk_or_the_page_grid(self, page, quantized,
+                                                  grid):
+        """A model-dtype pool whose pages span whole 8-row tiles takes
+        the walk, one program per lane; an int8 pool, or pages the chip
+        cannot place in a block, take one grid step per (lane, page)."""
+        q = jnp.zeros((2, 8, 32), jnp.float32)
+        pool = jnp.zeros((7, 2, page, 32), jnp.int8 if quantized
+                         else jnp.float32)
+        scales = [jnp.ones((7, 2, page), jnp.float32)] * 2 * quantized
+        bt, ln = jnp.ones((2, 3), jnp.int32), jnp.ones((2,), jnp.int32)
+        jaxpr = jax.make_jaxpr(paged_decode_attention_pallas)(
+            q, pool, pool, bt, ln, *scales)
+        eqns = list(jaxpr.eqns)
+        while eqns:
+            e = eqns.pop()
+            if e.primitive.name == "pallas_call":
+                assert e.params["grid_mapping"].grid == grid
+                return
+            eqns += [x for v in e.params.values()
+                     for x in getattr(getattr(v, "jaxpr", None), "eqns", ())]
+        pytest.fail("no pallas_call in the traced kernel")
 
     def test_serving_traffic_derives_kv_bytes_from_dtype(self):
         """The paged cache stores KV at the model dtype: an f32 model

@@ -16,16 +16,22 @@ corners the happy-path identity checks (table15) can miss:
   * CoW forks on a shared quantised page copy the scales with the
     codes,
   * chunked prefill equals whole-prompt prefill under int8 (per-token
-    quantisation commutes with chunking).
+    quantisation commutes with chunking),
+  * the fused kernel over int8 pages matches the dequantised reference
+    with NaN scales behind every dead page, on the model-dtype walk's
+    page-16 case.
 """
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs import get_config
 from repro.kernels.paged_decode_attention.ops import paged_decode_attention
+from repro.kernels.paged_decode_attention.paged_decode_attention import (
+    paged_decode_attention_pallas)
 from repro.kernels.paged_decode_attention.ref import (
     paged_decode_attention_quant_ref)
 from repro.models import Model
@@ -140,6 +146,34 @@ class TestGarbageSentinel:
             np.testing.assert_array_equal(
                 a, b, err_msg=f"{route.__name__} read the poisoned "
                               f"sentinel page")
+
+
+def test_int8_kernel_matches_ref_with_nan_behind_every_dead_page(capsys):
+    """test_paged_kernel's page-16 walk case (a table of 70 pages,
+    lengths 0 to the whole table) on int8 codes: NaN scales and codes
+    127 on every page no lane reads leave the output equal to the
+    dequantised reference over the clean pool."""
+    from test_paged_kernel import WALKS, walk_case
+    page, max_blocks, lengths = WALKS[0]
+    bt, ln, n_pages, dead = walk_case(page, max_blocks, lengths)
+    ks = [jax.random.fold_in(KEY, i) for i in range(5)]
+    Hkv, hd = 2, 32
+    codes = [jax.random.randint(k, (n_pages, Hkv, page, hd), -127, 128,
+                                jnp.int32).astype(jnp.int8) for k in ks[:2]]
+    # dequantised values of unit scale, as the f32 walk's normal pools,
+    # so the same tolerance holds
+    scales = [jax.random.uniform(k, (n_pages, Hkv, page), jnp.float32,
+                                 0.004, 0.012) for k in ks[2:4]]
+    q = jax.random.normal(ks[4], (len(lengths), 8, hd), jnp.float32)
+    ref = paged_decode_attention_quant_ref(q, *codes, *scales, bt, ln)
+    out = paged_decode_attention_pallas(
+        q, *(c.at[dead].set(127) for c in codes), bt, ln,
+        *(s.at[dead].set(jnp.nan) for s in scales),
+        interpret=pltpu.InterpretParams(detect_races=True))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+    assert bool(jnp.all(out[0] == 0))
+    assert "RACE" not in capsys.readouterr().out
 
 
 class TestHostTierBlobs:

@@ -71,15 +71,24 @@ def _spec(shape, dtype, sharding):
 
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
-def test_paged_decode_attention_compiles(one_chip, kv_dtype):
+# 4 x 128 pages: 2048 tokens a slot; 8 x 608: serve_long's 8 lanes of
+# 9,728 tokens (bench/traffic/serve_long.json), a table of 19 blocks;
+# page 8, the smallest page the walk places; page 4, which takes the page
+# grid
+@pytest.mark.parametrize("slots,max_blocks,page",
+                         [(SLOTS, MAX_BLOCKS, PAGE), (8, 608, PAGE),
+                          (SLOTS, 256, 8), (SLOTS, 512, 4)],
+                         ids=["4x128", "serve_long", "page8", "page4"])
+def test_paged_decode_attention_compiles(one_chip, kv_dtype, slots,
+                                         max_blocks, page):
     Hq, Hkv, hd = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
-    n_pages = 1 + SLOTS * MAX_BLOCKS
-    pool = _spec((n_pages, Hkv, PAGE, hd), kv_dtype, one_chip)
-    args = [_spec((SLOTS, Hq, hd), jnp.bfloat16, one_chip), pool, pool,
-            _spec((SLOTS, MAX_BLOCKS), jnp.int32, one_chip),
-            _spec((SLOTS,), jnp.int32, one_chip)]
+    n_pages = 1 + slots * max_blocks
+    pool = _spec((n_pages, Hkv, page, hd), kv_dtype, one_chip)
+    args = [_spec((slots, Hq, hd), jnp.bfloat16, one_chip), pool, pool,
+            _spec((slots, max_blocks), jnp.int32, one_chip),
+            _spec((slots,), jnp.int32, one_chip)]
     if kv_dtype == jnp.int8:
-        scales = _spec((n_pages, Hkv, PAGE), jnp.float32, one_chip)
+        scales = _spec((n_pages, Hkv, page), jnp.float32, one_chip)
         args += [scales, scales]
     hlo = _compile(paged_decode_attention_pallas, *args)
     assert "tpu_custom_call" in hlo
